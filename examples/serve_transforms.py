@@ -3,10 +3,10 @@
 Four tenants share one :class:`~repro.serve.TransformService`: two cutoffs
 × two k-shifts (three batch-compatibility classes — the two k-shifts of
 the large cutoff coalesce into shared stacked dispatches, the small cutoff
-rides its own), every request checked bitwise against per-request eager
-dispatch.  Ends by printing the service's metrics summary: per-tenant
-p50/p99 latency, requests/s, realized padding fraction, and the shared
-PlanCache's hit rate over the trace.
+rides its own), every request checked against per-request eager
+dispatch (to ``EAGER_RTOL``).  Ends by printing the service's metrics
+summary: per-tenant p50/p99 latency, requests/s, realized padding
+fraction, and the shared PlanCache's hit rate over the trace.
 
 Run:  PYTHONPATH=src python examples/serve_transforms.py \\
           [--requests 32] [--n 16] [--d 8] [--grid 1] [--budget 0.5] \\
@@ -25,6 +25,7 @@ import numpy as np
 from repro.core import ProcGrid, global_plan_cache, kpoint_sphere
 from repro.obs.trace import get_tracer
 from repro.serve import TransformService
+from repro.serve.transform_service import EAGER_RTOL
 
 
 def build_trace(n, d, d_small, requests, rng):
@@ -78,16 +79,17 @@ def main(argv=None):
     handles = [svc.submit(t, c, s, v_eff=v) for t, c, s, v in trace]
     svc.run_until_idle()
 
-    mismatches = sum(
-        not np.array_equal(h.result(10), svc.eager_apply(c, s, v))
-        for h, (_, c, s, v) in zip(handles, trace))
+    def off(out, ref):
+        return np.abs(out - ref).max() > EAGER_RTOL * np.abs(ref).max()
+    mismatches = sum(off(h.result(10), svc.eager_apply(c, s, v))
+                     for h, (_, c, s, v) in zip(handles, trace))
     m = svc.metrics.summary()
     print(json.dumps(m, indent=2))
     print(f"coalesced {m['coalesced_dispatches']}/{m['dispatches']} "
           f"dispatches, padding ≤ {m['padding_fraction_max']:.3f} "
           f"(budget {args.budget})")
     assert mismatches == 0, f"{mismatches} results differ from eager"
-    print("all results bitwise-equal to eager dispatch ✓")
+    print(f"all results within {EAGER_RTOL:g} of eager dispatch ✓")
     if args.trace_out:
         tr = get_tracer()
         tr.disable()
@@ -98,4 +100,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

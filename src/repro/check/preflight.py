@@ -222,11 +222,6 @@ def _basis_plan_bytes(spheres, segments, nbands: int, n: int, d: int
     return per_k + stacked + dft
 
 
-#: VMEM budget the fused sphere-pack kernels' per-plane working set must
-#: fit (one x-plane's first-stage slab + the resident packed operands).
-_PALLAS_VMEM_BYTES = 16 * 2 ** 20
-
-
 def preflight_basis(n: int, *, diameter: int | None = None,
                     kpts=((0.0, 0.0, 0.0),), nbands: int = 4,
                     grid=None, grid_shape=None, batch_axes=None,
@@ -327,18 +322,18 @@ def preflight_basis(n: int, *, diameter: int | None = None,
                     hint="shrink the cube/cutoff below the crossover or "
                          "request backend='jnp' explicitly"))
             else:
-                # fused unpack-DFT working set per grid step: one
-                # x-plane's (B_loc, ey, n) re/im slab plus the resident
-                # packed operands, DFT planes and line tables — all f32
+                from ..kernels import sphere_pack
+                # either fused kernel's VMEM per device: the batch rows
+                # split over the batch axes, the x planes over the fft
+                # axes, the packed rows stay whole
                 b_loc = max(nk * int(nbands) // max(bp, 1), 1)
                 npk = int(math.pi / 6.0 * d ** 3) + 1
-                slab = (8 * b_loc * (d * n + npk) + 8 * n * d
-                        + 12 * b_loc * d)
-                if slab > _PALLAS_VMEM_BYTES:
+                need = sphere_pack.vmem_bytes(b_loc, npk, d, n, d)
+                if need > sphere_pack.VMEM_LIMIT_BYTES:
                     diags.append(error(
                         "FFTB118",
-                        f"fused sphere-pack working set ~{slab} bytes "
-                        f"per x-plane exceeds the {_PALLAS_VMEM_BYTES}-"
+                        f"fused sphere-pack kernels need ~{need} bytes of "
+                        f"VMEM, past the {sphere_pack.VMEM_LIMIT_BYTES}-"
                         "byte VMEM budget",
                         location="backend",
                         hint="shrink nbands/nk or the cutoff diameter, "

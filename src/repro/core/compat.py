@@ -1,85 +1,48 @@
-"""JAX version compatibility shims.
+"""Thin wrappers over the JAX mesh and ``shard_map`` APIs the repo uses.
 
-The repo targets the mesh/shard_map APIs of recent JAX, but must also run on
-older releases (e.g. 0.4.3x) where
-
-  * ``jax.sharding.AxisType`` does not exist (meshes have no axis types —
-    every axis behaves like the later ``AxisType.Auto``),
-  * ``jax.make_mesh`` / ``Mesh`` take no ``axis_types`` keyword,
-  * ``jax.sharding.AbstractMesh`` is constructed from ``((name, size), ...)``
-    pairs instead of ``(shape, names)``,
-  * ``shard_map`` lives in ``jax.experimental.shard_map`` and spells its
-    replication check ``check_rep`` rather than ``check_vma``.
-
-Everything that touches those APIs goes through this module so the rest of
-the codebase can be written against one surface.
+They spell two choices once, so call sites stay short: every mesh axis is
+``AxisType.Auto``, and ``shard_map`` runs with its replication check off
+(the FFT bodies mix collectives whose replication the checker cannot see).
 """
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
-from jax.sharding import Mesh
-
-try:  # newer JAX
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - exercised on old JAX only
-    AxisType = None
-
-HAS_AXIS_TYPES = AxisType is not None
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 
-def _auto_axis_types(n: int):
-    return (AxisType.Auto,) * n if HAS_AXIS_TYPES else None
+def _auto(n: int) -> tuple:
+    return (AxisType.Auto,) * n
 
 
 def make_mesh(shape: Sequence[int], names: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported."""
+    """``jax.make_mesh`` with Auto axis types."""
     shape, names = tuple(shape), tuple(names)
-    if HAS_AXIS_TYPES:
-        return jax.make_mesh(shape, names,
-                             axis_types=_auto_axis_types(len(shape)))
-    return jax.make_mesh(shape, names)
+    return jax.make_mesh(shape, names, axis_types=_auto(len(shape)))
 
 
 def mesh_from_devices(dev_array, names: Sequence[str]) -> Mesh:
-    """``Mesh(devices, names)`` with Auto axis types where supported."""
+    """``Mesh(devices, names)`` with Auto axis types."""
     names = tuple(names)
-    if HAS_AXIS_TYPES:
-        return Mesh(dev_array, names,
-                    axis_types=_auto_axis_types(len(names)))
-    return Mesh(dev_array, names)
+    return Mesh(dev_array, names, axis_types=_auto(len(names)))
 
 
 def abstract_mesh(shape: Sequence[int], names: Sequence[str]):
     """Device-less mesh for plan construction/inspection."""
-    from jax.sharding import AbstractMesh
-    shape, names = tuple(shape), tuple(names)
-    try:
-        return AbstractMesh(shape, names)
-    except TypeError:  # old signature: tuple of (name, size) pairs
-        return AbstractMesh(tuple(zip(names, shape)))
+    return AbstractMesh(tuple(shape), tuple(names))
 
 
 def is_tracer(x) -> bool:
     """True when ``x`` is an abstract tracer (inside jit/vmap tracing).
 
     Placement pinning must switch from ``device_put`` (eager arrays) to
-    ``with_sharding_constraint`` (tracers); ``jax.core.Tracer`` is the
-    stable spelling on every supported version, with a duck-typed
-    fallback should a future release drop it.
+    ``with_sharding_constraint`` (tracers).
     """
-    try:
-        return isinstance(x, jax.core.Tracer)
-    except AttributeError:  # pragma: no cover - future jax without jax.core
-        return type(x).__name__.endswith("Tracer")
+    return isinstance(x, jax.core.Tracer)
 
 
 def shard_map(body, mesh, in_specs, out_specs):
-    """``shard_map`` with the replication check off, any JAX version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with the replication check off."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -134,15 +134,13 @@ class SphereDomain(Domain):
         (gather) stages; built once per plan."""
         ex, ey, ez = self.extents
         (xl, yl, zl) = self.lower
-        out = np.empty(self.npacked, np.int64)
-        p = 0
-        for c in range(self.ncols):
-            x = self._col_x[c] - xl
-            y = self._col_y[c] - yl
-            for z in range(self._z_lo[c] - zl, self._z_hi[c] - zl):
-                out[p] = (x * ey + y) * ez + z
-                p += 1
-        return out
+        lens = (self._z_hi - self._z_lo).astype(np.int64)
+        first = ((self._col_x.astype(np.int64) - xl) * ey
+                 + (self._col_y - yl)) * ez + (self._z_lo - zl)
+        # column c's lanes are first[c], first[c] + 1, … (z ascending)
+        step = np.arange(self.npacked, dtype=np.int64) \
+            - np.repeat(self._row_ptr[:-1], lens)
+        return np.repeat(first, lens) + step
 
     def mask(self) -> np.ndarray:
         """Boolean occupancy mask of the bounding cuboid (x, y, z)."""

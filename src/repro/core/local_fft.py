@@ -29,6 +29,11 @@ _BACKENDS = ("jnp", "matmul", "pallas")
 # crossover above which a single dense-DFT matmul stops being the right tool
 # and the four-step factorization takes over (kernels/ops.py).
 MATMUL_MAX_N = 2048
+#: precision of the line-DFT GEMMs.  A TPU's default f32 ``dot`` is one
+#: bf16 pass (~3e-3 relative error at n=256); HIGHEST keeps the transform
+#: within f32 rounding of an f64 FFT.  The CPU computes f32 dots in f32
+#: either way, so CPU results do not depend on it.
+DFT_PRECISION = jax.lax.Precision.HIGHEST
 
 
 @functools.lru_cache(maxsize=128)
@@ -97,8 +102,10 @@ def _matmul_backend(x, axis, n_in, n_out, inverse):
     xm = _move_last(x, axis)
     xr, xi = jnp.real(xm), jnp.imag(xm)
     # y = x @ W^T with complex split into real MXU GEMMs
-    yr = xr @ wr.T - xi @ wi.T
-    yi = xr @ wi.T + xi @ wr.T
+    def mm(a, w):
+        return jnp.matmul(a, w.T, precision=DFT_PRECISION)
+    yr = mm(xr, wr) - mm(xi, wi)
+    yi = mm(xr, wi) + mm(xi, wr)
     y = jax.lax.complex(yr, yi)
     return jnp.moveaxis(y, -1, axis)
 

@@ -108,7 +108,7 @@ def _fused_unpack_parts(wrapper, spheres, nbands: int, npacked: int):
     xentry = _pspec_entry(grid, lay.get(xdim, ()))
     t_spec = P(bentry, xentry)        # line tables split with the x planes
     in_specs = (P(bentry, None), t_spec, t_spec, t_spec,
-                P(xentry, None), P(None, None), P(None, None))
+                P(xentry), P(None, None), P(None, None))
     fn = jax.jit(compat.shard_map(body, grid.mesh, in_specs, mid.pspec))
     tables = (jnp.asarray(start), jnp.asarray(zlo), jnp.asarray(cnt),
               jnp.asarray(flag), wr, wi)
@@ -122,10 +122,11 @@ def _fused_pack_parts(wrapper, spheres, nbands: int, npacked: int):
     The mirror of :func:`_fused_unpack_parts`: when the plan *closes* with
     a local truncating line-DFT on the trailing dim, a derived *lead* plan
     runs every stage but the last, and ``sphere_pack.dft_pack`` fuses that
-    final n→d stage with the CSR gather to ``(B, npacked)``.  Lane
-    localization happens inside the shard_map body (each shard owns a
-    contiguous x-plane range; lanes outside it are masked and merged by a
-    psum over the fft axes), so padded lanes still come out exactly zero.
+    final n→d stage with the CSR gather to ``(B, npacked)``.  The line
+    tables split with the x planes, so each shard merges only the lines
+    of its own contiguous x-plane range; a psum over the fft axes joins
+    the shards' disjoint lanes, and padded lanes still come out exactly
+    zero.
     """
     from ..kernels import sphere_pack
 
@@ -147,7 +148,7 @@ def _fused_pack_parts(wrapper, spheres, nbands: int, npacked: int):
     if B != len(spheres) * nbands:
         return None
 
-    lg, zz, vv = sphere_pack.pack_gather_tables(spheres, nbands, npacked)
+    start, zlo, cnt, flag = sphere_pack.line_tables(spheres, nbands)
     wr, wi, _ = dft_matrix_device(st.n_out, st.n_in, st.inverse)
     mid = DistTensor(tout.domains[:-1]
                      + (Domain((0, 0, 0), (ex - 1, ey - 1, st.n_in - 1)),),
@@ -157,37 +158,30 @@ def _fused_pack_parts(wrapper, spheres, nbands: int, npacked: int):
                    inverse=p.is_inverse, backend=wrapper.backend,
                    policy=wrapper.policy, _stages=p.stages[:-1],
                    _scale=1.0)
-    x_axes = tuple(lay.get(xdim, ()))
-    names = tuple(grid.axis_name(a) for a in x_axes)
-    sizes = tuple(grid.shape[a] for a in x_axes)
-    d_out = st.n_out
+    names = tuple(grid.axis_name(a) for a in lay.get(xdim, ()))
 
-    def body(slab, lg, zz, vv, wr, wi):
+    def body(slab, start, zlo, cnt, flag, wr, wi):
         slab = slab.astype(jnp.complex64)
-        xr, xi = jnp.real(slab), jnp.imag(slab)
-        ex_loc, ey_loc = xr.shape[1], xr.shape[2]
-        ix = 0                       # flattened shard index over the x axes
-        for nm, s in zip(names, sizes):
-            ix = ix * s + jax.lax.axis_index(nm)
-        ll = lg - ix * ex_loc * ey_loc          # global line → local line
-        nloc = ex_loc * ey_loc
-        ok = ((ll >= 0) & (ll < nloc) & (vv != 0)).astype(jnp.int32)
-        g = jnp.clip(ll * d_out + zz, 0, nloc * d_out - 1).astype(jnp.int32)
-        pr, pi = sphere_pack.dft_pack(xr, xi, g, ok, wr, wi)
+        pr, pi = sphere_pack.dft_pack(jnp.real(slab), jnp.imag(slab), start,
+                                      zlo, cnt, flag, wr, wi,
+                                      npacked=npacked)
         out = jax.lax.complex(pr, pi)
         if names:
-            # each lane is gathered on exactly one shard (zeros elsewhere)
+            # each line is merged on exactly one shard (zeros elsewhere)
             out = jax.lax.psum(out, names)
         return out
 
     bentry = _pspec_entry(grid, lay.get(bdim, ()))
-    in_specs = (mid.pspec, P(bentry, None), P(bentry, None),
-                P(bentry, None), P(None, None), P(None, None))
+    xentry = _pspec_entry(grid, lay.get(xdim, ()))
+    t_spec = P(bentry, xentry)        # line tables split with the x planes
+    in_specs = (mid.pspec, t_spec, t_spec, t_spec, P(xentry),
+                P(None, None), P(None, None))
     fn = jax.jit(compat.shard_map(body, grid.mesh, in_specs,
                                   P(bentry, None)))
-    tables = (jnp.asarray(lg), jnp.asarray(zz), jnp.asarray(vv), wr, wi)
+    tables = (jnp.asarray(start), jnp.asarray(zlo), jnp.asarray(cnt),
+              jnp.asarray(flag), wr, wi)
     return {"fn": fn, "lead": lead, "tables": tables,
-            "out_shape": (B, npacked), "private": tables[:3]}
+            "out_shape": (B, npacked), "private": tables[:4]}
 
 
 class _FusedTransformMixin:

@@ -52,6 +52,7 @@ PW_SPEC = planewave_spec()
 CUBE_SPEC = cube_spec()
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class StackedBandTables:
     """Dense per-k tables for the batched band-update engine.
@@ -71,6 +72,7 @@ class StackedBandTables:
     Cached in the process-global ``PlanCache`` next to the stacked plan
     pair (same key ingredients), so every SCF iteration after the first
     is a cache hit; the cache bills the three tables as private bytes.
+    A pytree, so the jitted SCF step takes the tables as an argument.
     """
 
     kinetic: jnp.ndarray
@@ -402,12 +404,16 @@ class PlaneWaveBasis:
 
     def _build_band_tables(self, spheres) -> StackedBandTables:
         kin_np, valid = padded_kinetic_table(list(spheres), self.L)
-        kin = self.grid.replicate(jnp.asarray(kin_np))
-        mask = self.grid.replicate(
-            jnp.asarray(valid.astype(np.float32)))
-        # same f32 ops as the per-k 1/(1 + kinetic(ik)) preconditioner, so
-        # valid lanes agree bitwise; mask zeroes the padded lanes exactly
-        precond = self.grid.replicate(mask / (1.0 + kin))
+        # built eagerly even when the first request comes from inside a
+        # jit trace: the cache must hold arrays, never tracers
+        with jax.ensure_compile_time_eval():
+            kin = self.grid.replicate(jnp.asarray(kin_np))
+            mask = self.grid.replicate(
+                jnp.asarray(valid.astype(np.float32)))
+            # same f32 ops as the per-k 1/(1 + kinetic(ik))
+            # preconditioner, so valid lanes agree bitwise; mask zeroes
+            # the padded lanes exactly
+            precond = self.grid.replicate(mask / (1.0 + kin))
         return StackedBandTables(kinetic=kin, mask=mask, precond=precond)
 
     def cube_plans(self):

@@ -31,15 +31,18 @@ class HartreeSolver:
         self.basis = basis
         self.kernel = coulomb_kernel(basis.n, basis.L)
 
-    def __call__(self, rho):
+    def __call__(self, rho, kernel=None):
         """ρ(r) → v_H(r), both real (n, n, n) fields.
 
         One forward full-cube plan, a diagonal multiply in G-space, one
         derived-inverse full-cube plan — two distributed transforms.
+        ``kernel`` stands in for ``self.kernel`` where a jitted caller
+        passes the Coulomb kernel as an argument.
         """
         fwd, inv = self.basis.cube_plans()
         rho_g = fwd(rho.astype(jnp.complex64))
-        return jnp.real(inv(rho_g * self.kernel))
+        kern = self.kernel if kernel is None else kernel
+        return jnp.real(inv(rho_g * kern))
 
     def energy(self, rho, vh) -> float:
         """E_H = ½ ∫ ρ v_H  (discretized with ΔV)."""

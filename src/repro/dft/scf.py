@@ -238,6 +238,8 @@ class SCFResult:
                                       # realized per-segment padding, each
                                       # ≤ the configured segment_padding
     jitted: bool = False              # iterations ran as the fused jit step
+    compile_seconds: float = 0.0      # the fused step's trace + compile,
+                                      # outside ``seconds`` (0 when eager)
     #: per-iteration telemetry: one dict per outer iteration with
     #: {iteration, energy, residual, seconds, transforms} — the record
     #: the observability layer attaches so a slow run can be broken
@@ -320,37 +322,21 @@ def total_energy_stacked(basis, c_pad, rho, v_ext, hartree: HartreeSolver,
 
 
 # -------------------------------------------------------------------- driver
-def _jit_scf_loop(cfg: SCFConfig, basis, v_ext, hartree, occ,
-                  nelec: float, coeffs, callback):
-    """The fused SCF loop: one jit-compiled step per outer iteration.
+def make_scf_step(cfg: SCFConfig, basis, hartree, occ, nelec: float):
+    """One fused outer SCF iteration as a pure, jittable function.
 
-    Everything the eager loop does per iteration — v_eff build, the
-    stacked band update, density rebuild, total energy, residual, density
-    mixing — is traced into a single XLA computation with the density,
-    band-coefficient and mixer buffers donated, so iterations after the
-    first dispatch no per-k Python work at all.  Plans and band tables
-    come from the process-global PlanCache *eagerly at trace time* (the
-    fetches below and inside the first ``step`` call), which keeps cache
-    traffic honestly accounted: one fetch per traced transform, zero per
-    steady-state iteration.
-
-    Returns (energies, residuals, eigs, ρ_out, transforms, converged,
-    seconds) with the same accounting semantics as the eager loop.
+    ``step(rho, c_segs, mix_state, v_ext, coulomb, tables)`` → (ρ_next,
+    c_segs', mix_state', ρ_out, eps per segment, energy, residual).  The
+    cube-sized fields (``v_ext``, the Coulomb kernel) and the per-segment
+    band tables are arguments, not closed-over arrays, so the compiled
+    program does not carry them as constants.  Plans come from the
+    process-global PlanCache at trace time.
     """
     segs = basis.segments
-    invs = [basis.stacked_hamiltonian_plans(s)[0] for s in range(len(segs))]
-    tables = [basis.stacked_band_tables(s) for s in range(len(segs))]
-    c_segs = tuple(
-        invs[s].stack([coeffs[ik] for ik in seg]).reshape(
-            len(seg), basis.nbands, invs[s].npacked_max)
-        for s, seg in enumerate(segs))
-    rho = sum(density_from_stacked(basis, c_segs[s], occ, seg=s)
-              for s in range(len(segs)))
-    mix_state = jit_mixer_init(basis.n ** 3, cfg.mix_history)
     inelec = 1.0 / max(nelec, 1e-9)
 
-    def step(rho, c_segs, mix_state):
-        vh = hartree(rho)
+    def step(rho, c_segs, mix_state, v_ext, coulomb, tables):
+        vh = hartree(rho, coulomb)
         v_eff = v_ext + vh
         if cfg.xc:
             v_eff = v_eff + lda_exchange(rho)[1]
@@ -365,9 +351,9 @@ def _jit_scf_loop(cfg: SCFConfig, basis, v_ext, hartree, occ,
         c_new = tuple(c_new)
         rho_out = sum(density_from_stacked(basis, c_new[s], occ, seg=s)
                       for s in range(len(segs)))
-        energy = total_energy_stacked(basis, c_new, rho_out, v_ext,
-                                      hartree, occ, xc=cfg.xc,
-                                      tables=tables)
+        energy = total_energy_stacked(
+            basis, c_new, rho_out, v_ext,
+            lambda r: hartree(r, coulomb), occ, xc=cfg.xc, tables=tables)
         resid = (jnp.linalg.norm(rho_out - rho)
                  * jnp.float32(basis.dv ** 0.5 * inelec))
         mix_state, rho_next = jit_mix(mix_state, rho, rho_out,
@@ -376,7 +362,48 @@ def _jit_scf_loop(cfg: SCFConfig, basis, v_ext, hartree, occ,
         return (rho_next, c_new, mix_state, rho_out,
                 tuple(eps_segs), energy, resid)
 
-    step = jax.jit(step, donate_argnums=(0, 1, 2))
+    return step
+
+
+def _jit_scf_loop(cfg: SCFConfig, basis, v_ext, hartree, occ,
+                  nelec: float, coeffs, callback):
+    """The fused SCF loop: one jit-compiled step per outer iteration.
+
+    Everything the eager loop does per iteration — v_eff build, the
+    stacked band update, density rebuild, total energy, residual, density
+    mixing — is traced into a single XLA computation
+    (:func:`make_scf_step`) with the density, band-coefficient and mixer
+    buffers donated, so iterations after the first dispatch no per-k
+    Python work at all.  Plans and band tables come from the
+    process-global PlanCache *eagerly at trace time*, which keeps cache
+    traffic honestly accounted: one fetch per traced transform, zero per
+    steady-state iteration.  The step is compiled ahead of the loop, and
+    that compile is timed apart from the iterations.
+
+    Returns (energies, residuals, records, eigs, ρ_out, transforms,
+    converged, seconds, compile_seconds) with the same accounting
+    semantics as the eager loop.
+    """
+    segs = basis.segments
+    invs = [basis.stacked_hamiltonian_plans(s)[0] for s in range(len(segs))]
+    tables = tuple(basis.stacked_band_tables(s) for s in range(len(segs)))
+    c_segs = tuple(
+        invs[s].stack([coeffs[ik] for ik in seg]).reshape(
+            len(seg), basis.nbands, invs[s].npacked_max)
+        for s, seg in enumerate(segs))
+    rho = sum(density_from_stacked(basis, c_segs[s], occ, seg=s)
+              for s in range(len(segs)))
+    mix_state = jit_mixer_init(basis.n ** 3, cfg.mix_history)
+    consts = (v_ext, hartree.kernel, tables)
+    t_c = time.perf_counter()
+    step = jax.jit(make_scf_step(cfg, basis, hartree, occ, nelec),
+                   donate_argnums=(0, 1, 2)
+                   ).lower(rho, c_segs, mix_state, *consts).compile()
+    compile_seconds = time.perf_counter() - t_c
+    # a compiled step takes exactly the placements it was compiled for;
+    # on a multi-device grid its outputs may come back placed otherwise
+    in_sh = step.input_shardings[0]
+    consts = jax.device_put(consts, in_sh[3:])
 
     energies: list[float] = []
     residuals: list[float] = []
@@ -394,8 +421,9 @@ def _jit_scf_loop(cfg: SCFConfig, basis, v_ext, hartree, occ,
     for it in range(cfg.max_iter):
         it_t0 = time.perf_counter()
         with tr.span("scf_iteration", iteration=it, route="jit"):
+            state = jax.device_put((rho, c_segs, mix_state), in_sh[:3])
             rho, c_segs, mix_state, rho_out, eps_segs, energy, resid = \
-                step(rho, c_segs, mix_state)
+                step(*state, *consts)
             # the float() conversions sync on the step's outputs, so
             # the span and the per-iteration seconds cover real work
             energy = float(energy)
@@ -422,7 +450,7 @@ def _jit_scf_loop(cfg: SCFConfig, basis, v_ext, hartree, occ,
     jax.block_until_ready((rho, rho_out))
     seconds = time.perf_counter() - t0
     return energies, residuals, records, eigs, rho_out, transforms, \
-        converged, seconds
+        converged, seconds, compile_seconds
 
 
 def _init_coefficients(basis, seed: int):
@@ -483,10 +511,11 @@ def run_scf(cfg: SCFConfig, *, grid: ProcGrid | None = None,
 
     coeffs = _init_coefficients(basis, cfg.seed)
 
+    compile_seconds = 0.0
     if cfg.jit_step:
         (energies, residuals, iteration_records, eigs, rho, transforms,
-         converged, seconds) = _jit_scf_loop(cfg, basis, v_ext, hartree,
-                                             occ, nelec, coeffs, callback)
+         converged, seconds, compile_seconds) = _jit_scf_loop(
+             cfg, basis, v_ext, hartree, occ, nelec, coeffs, callback)
     else:
         rho = density_from_orbitals(basis, coeffs, occ)
         mixer = AndersonMixer(cfg.mix_alpha, cfg.mix_history,
@@ -581,6 +610,7 @@ def run_scf(cfg: SCFConfig, *, grid: ProcGrid | None = None,
         band_update="stacked" if stacked else "per-k",
         backend=basis.backend,
         jitted=bool(cfg.jit_step),
+        compile_seconds=compile_seconds,
         segments=basis.nsegments,
         segment_padding_fractions=basis.segment_padding_fractions,
         iteration_records=iteration_records)

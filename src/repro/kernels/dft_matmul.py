@@ -28,24 +28,44 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core import local_fft
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """Pallas interpret mode for one kernel call.
+
+    An explicit choice wins.  Otherwise the default backend decides, at
+    call time: Mosaic on a TPU, the Pallas interpreter on the CPU (tests
+    and rehearsals).  Any other platform raises — these kernels are TPU
+    kernels, and interpreting them on an accelerator would hide that.
+    """
+    if interpret is not None:
+        return bool(interpret)
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise RuntimeError(f"no Pallas route for platform {platform!r}: the "
+                       "kernels compile for TPU and interpret on CPU only")
+
+
+def dot_t(a, b):
+    """a · bᵀ on the MXU, accumulated in f32 at the line-DFT precision
+    (``local_fft.DFT_PRECISION``)."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               precision=local_fft.DFT_PRECISION,
+                               preferred_element_type=jnp.float32)
+
 
 def _kernel(xr_ref, xi_ref, wr_ref, wi_ref, yr_ref, yi_ref):
     xr = xr_ref[...]
     xi = xi_ref[...]
     wr = wr_ref[...]
     wi = wi_ref[...]
-    f32 = jnp.float32
     # 4 real GEMMs on the MXU; accumulate in f32 regardless of input dtype
-    rr = jax.lax.dot_general(xr, wr, (((1,), (1,)), ((), ())),
-                             preferred_element_type=f32)
-    ii = jax.lax.dot_general(xi, wi, (((1,), (1,)), ((), ())),
-                             preferred_element_type=f32)
-    ri = jax.lax.dot_general(xr, wi, (((1,), (1,)), ((), ())),
-                             preferred_element_type=f32)
-    ir = jax.lax.dot_general(xi, wr, (((1,), (1,)), ((), ())),
-                             preferred_element_type=f32)
-    yr_ref[...] = (rr - ii).astype(yr_ref.dtype)
-    yi_ref[...] = (ri + ir).astype(yi_ref.dtype)
+    yr_ref[...] = (dot_t(xr, wr) - dot_t(xi, wi)).astype(yr_ref.dtype)
+    yi_ref[...] = (dot_t(xr, wi) + dot_t(xi, wr)).astype(yi_ref.dtype)
 
 
 def _kernel_twiddle(xr_ref, xi_ref, wr_ref, wi_ref, tr_ref, ti_ref,
@@ -54,33 +74,30 @@ def _kernel_twiddle(xr_ref, xi_ref, wr_ref, wi_ref, tr_ref, ti_ref,
     xi = xi_ref[...]
     wr = wr_ref[...]
     wi = wi_ref[...]
-    f32 = jnp.float32
-    rr = jax.lax.dot_general(xr, wr, (((1,), (1,)), ((), ())),
-                             preferred_element_type=f32)
-    ii = jax.lax.dot_general(xi, wi, (((1,), (1,)), ((), ())),
-                             preferred_element_type=f32)
-    ri = jax.lax.dot_general(xr, wi, (((1,), (1,)), ((), ())),
-                             preferred_element_type=f32)
-    ir = jax.lax.dot_general(xi, wr, (((1,), (1,)), ((), ())),
-                             preferred_element_type=f32)
-    yr = rr - ii
-    yi = ri + ir
+    yr = dot_t(xr, wr) - dot_t(xi, wi)
+    yi = dot_t(xr, wi) + dot_t(xi, wr)
     tr = tr_ref[...]            # (bm, bn): per-row twiddles, pre-broadcast
     ti = ti_ref[...]
     yr_ref[...] = (yr * tr - yi * ti).astype(yr_ref.dtype)
     yi_ref[...] = (yr * ti + yi * tr).astype(yi_ref.dtype)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("bm", "bn", "interpret"))
 def dft_matmul(xr, xi, wr, wi, tr=None, ti=None, *, bm: int = 256,
-               bn: int = 128, interpret: bool = False):
+               bn: int = 128, interpret: bool | None = None):
     """y = (xr + i·xi) @ (wr + i·wi)ᵀ [⊙ twiddle], shapes (B,K)·(N,K)→(B,N).
 
     B must be divisible by bm and N by bn (ops.py pads).  ``tr``/``ti`` are
     optional (B, N) twiddle factors fused into the epilogue.
+    ``interpret=None`` decides from the platform at call time
+    (:func:`resolve_interpret`).
     """
+    return _dft_matmul(xr, xi, wr, wi, tr, ti, bm=bm, bn=bn,
+                       interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "interpret"))
+def _dft_matmul(xr, xi, wr, wi, tr, ti, *, bm: int, bn: int,
+                interpret: bool):
     B, K = xr.shape
     N = wr.shape[0]
     assert B % bm == 0 and N % bn == 0, (B, N, bm, bn)

@@ -1,10 +1,10 @@
 """jit'd wrappers around the Pallas DFT kernel + the four-step composition.
 
-On CPU (this container) the kernels run with ``interpret=True``; on TPU the
-same code emits real Mosaic kernels.  ``dft_apply`` handles padding of the
-batch/frequency dims to the kernel tile sizes; ``four_step_dft`` factors
-large n into two MXU-sized stages with the twiddle fused into the first
-stage's epilogue.
+On CPU the kernels run with ``interpret=True``; on TPU the same code emits
+real Mosaic kernels (decided per call by ``dft_matmul.resolve_interpret``).
+``dft_apply`` handles padding of the batch/frequency dims to the kernel tile
+sizes; ``four_step_dft`` factors large n into two MXU-sized stages with the
+twiddle fused into the first stage's epilogue.
 """
 from __future__ import annotations
 
@@ -18,8 +18,6 @@ import numpy as np
 from repro.core.local_fft import dft_matrix
 from . import ref as _ref
 from .dft_matmul import dft_matmul
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def _pad_to(x, m: int, axis: int):
@@ -48,7 +46,6 @@ def dft_apply(x, n_out: int | None = None, *, inverse: bool = False,
     Rectangular n_in≠n_out fuses zero-padding (n_in < n_out) or spectrum
     truncation (n_in > n_out) into the GEMM shape.
     """
-    interpret = _INTERPRET if interpret is None else interpret
     B, n_in = x.shape
     n_out = n_in if n_out is None else n_out
     w = dft_matrix(n_out, n_in, inverse)
@@ -87,7 +84,6 @@ def four_step_dft(x, *, inverse: bool = False, interpret: bool | None = None):
     the W_N^{j1·k2} twiddle fused into the kernel epilogue; stage 2: DFT_n1
     over rows; output re-rolled to natural order.
     """
-    interpret = _INTERPRET if interpret is None else interpret
     B, n = x.shape
     n1, n2 = _factor(n)
     # (B, n) -> (B, n2, n1): j = j1 + n1·j2, j1 fast

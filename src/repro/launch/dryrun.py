@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this produces the artifacts the roofline analysis reads:
@@ -20,6 +17,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import time
 
@@ -429,6 +427,10 @@ def _store(db):
 
 
 def main():
+    # 512 virtual host devices for the production meshes; set before the
+    # first device query initializes the CPU backend, and only for the
+    # CLI, so importing this module leaves the caller's devices alone
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

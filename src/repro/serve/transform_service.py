@@ -65,7 +65,17 @@ from .scheduler import (CoalescingScheduler, DeadlineExceeded, QueueFull,
                         TransformRequest, compat_key)
 
 __all__ = ["TransformService", "TransformRequest", "TransformHandle",
-           "DeadlineExceeded", "QueueFull", "ServiceStopped", "ServeError"]
+           "DeadlineExceeded", "QueueFull", "ServiceStopped", "ServeError",
+           "EAGER_RTOL"]
+
+
+#: Coalesced results match :meth:`TransformService.eager_apply` to this
+#: relative error in the max norm (``max|out − eager| ≤ EAGER_RTOL ·
+#: max|eager|``), not bitwise.  A coalesced batch runs its line-DFT GEMMs
+#: over another row count than the request's own plan, and the f32 GEMM
+#: blocking — hence the rounding — follows the batch shape; the gap seen
+#: is a few f32 ulps of the largest coefficient.
+EAGER_RTOL = 1e-5
 
 
 def _next_pow2(x: int) -> int:
@@ -321,7 +331,7 @@ class TransformService:
 
         Same math as one dispatched request (cached per-sphere
         ``PlaneWaveFFT`` pair, batch = the request's own bands); the
-        coalesced path must match this bitwise.
+        coalesced path must match this to ``EAGER_RTOL``.
         """
         coeffs = np.asarray(coeffs, np.complex64)
         bdom = Domain((0,), (coeffs.shape[0] - 1,))

@@ -185,19 +185,20 @@ def test_dft_pack_bitwise_and_padded_lanes_zero(d, n, nbands, kpts):
     ex, ey, _ = spheres[0].extents
     xr = rng.standard_normal((B, ex, ey, n)).astype(np.float32)
     xi = rng.standard_normal((B, ex, ey, n)).astype(np.float32)
-    line, zz, valid = sphere_pack.pack_gather_tables(spheres, nbands, npm)
-    g = line * d + zz
+    start, zlo, cnt, flag = sphere_pack.line_tables(spheres, nbands)
     wr, wi, _ = dft_matrix_device(d, n, False)
     pr, pi = sphere_pack.dft_pack(
-        jnp.asarray(xr), jnp.asarray(xi), jnp.asarray(g),
-        jnp.asarray(valid), wr, wi, interpret=True)
+        jnp.asarray(xr), jnp.asarray(xi), jnp.asarray(start),
+        jnp.asarray(zlo), jnp.asarray(cnt), jnp.asarray(flag), wr, wi,
+        npacked=npm, interpret=True)
     rr, ri = _composed_dft_pack(spheres, nbands, xr, xi,
                                 np.asarray(wr), np.asarray(wi), npm)
     assert np.abs(np.asarray(pr) - rr).max() == 0.0
     assert np.abs(np.asarray(pi) - ri).max() == 0.0
     # padded lanes are exact +0.0 whatever the slab held (the ragged
     # two-sphere case has them; a single sphere pads nothing)
-    pad = valid == 0
+    pad = np.arange(npm)[None, :] >= np.repeat(
+        [s.npacked for s in spheres], nbands)[:, None]
     assert pad.any() == (len(spheres) > 1)
     assert np.all(np.asarray(pr)[pad] == 0.0)
     assert np.all(np.asarray(pi)[pad] == 0.0)
@@ -239,7 +240,7 @@ def test_line_tables_round_trip():
                 flat.append(l * ez + zlo[k, l] + j)
                 assert start[k, l] + j == len(flat) - 1
         assert np.array_equal(np.asarray(flat), s.pack_indices())
-    assert flag.shape == (ex, 1) and flag.any()
+    assert flag.shape == (ex,) and flag.any()
 
 
 def test_realized_backend_and_flops():

@@ -47,6 +47,21 @@ def test_pack_indices_bijective(sph16):
     assert len(np.unique(idx)) == sph16.npacked
 
 
+@pytest.mark.parametrize("d,kpt", [(16, (0.0, 0.0, 0.0)), (9, (0.0, 0.0, 0.0)),
+                                   (8, (0.5, 0.25, 0.5))])
+def test_pack_indices_match_column_loop(d, kpt):
+    """The vectorized table equals the column-by-column CSR walk."""
+    from repro.core import kpoint_sphere
+    s = kpoint_sphere(d, kpt)
+    ex, ey, ez = s.extents
+    (xl, yl, zl), off = s.lower, s.offsets
+    ref = [((x - xl) * ey + (y - yl)) * ez + (z - zl)
+           for x, y, lo, hi in zip(off["col_x"], off["col_y"], off["z_lo"],
+                                   off["z_hi"])
+           for z in range(lo, hi)]
+    np.testing.assert_array_equal(s.pack_indices(), np.asarray(ref))
+
+
 def test_pack_unpack_roundtrip(sph16):
     g = ProcGrid.create([1])
     inv, _ = make_planewave_pair(g, 32, sph16, 2)
