@@ -9,8 +9,8 @@ process, with every input generated from ``--seed``:
                 at the Fig. 9 width (n=256, d=128), inverse then forward,
                 checked against a NumPy float64 FFT for two bands.
 3. scf        — the README demo to convergence against its pinned CPU
-                energy, then the jitted full-width SCF until it converges
-                or ``SCF_ITERS`` run; its residual must fall.
+                energy, then ``SCF_ITERS`` iterations of the jitted
+                full-width SCF; its residual must end below its start.
 4. service    — ``TransformService`` at n=256 with d=128 spheres at two
                 k-shifts and three tenants, checked against its eager
                 oracle.
@@ -24,9 +24,15 @@ The last line of standard output is one JSON object
 script exits non-zero without that line.
 
 ``--chips 4`` runs only the distributed phase: the same full-width
-transform, and a few jitted SCF iterations at half that width, on the 1D
-``(4,)`` fft grid and the 2×2 batch×fft grid, each against a 1-chip grid
-in the same process (and the transform against the NumPy reference).
+transform and a few jitted full-width SCF iterations on the 1D ``(4,)``
+fft grid and the 2×2 batch×fft grid, each against a 1-chip grid in the
+same process (and the transform against the NumPy reference).
+
+Memory is printed two ways: ``memory_analysis()`` of the chip's own
+compile (argument, output, temp and code bytes of one program), and the
+runtime's ``peak_bytes_in_use`` — a process-wide high-water mark of the
+array buffers it allocated since start, cumulative across phases, which
+does not count a program's temporaries.
 
 Run:  python chip_smoke.py [--seed 0] [--chips 1|4]
 Band counts are cut from the paper's 256 to what one chip holds; the cut
@@ -50,19 +56,20 @@ sys.path.insert(0, os.path.join(HERE, "src"))
 # ---------------------------------------------------------------- sizes
 KPTS = ((0.0, 0.0, 0.0), (0.5, 0.5, 0.5))
 #: bands per k-point for the full-width transform: 2 k × 16 bands is the
-#: largest power of two whose compiled peak stays under ~70% of 16 GB
-#: (v5e compile rehearsal: 7.2 GiB at 16, 13.8 GiB at 32)
+#: largest power of two whose compiled peak (argument + temp bytes of
+#: ``memory_analysis()``) stays under ~70% of 16 GB (v5e compile
+#: rehearsal: 7.2 GiB at 16, 13.8 GiB at 32)
 NB_TRANSFORM = 16
 #: bands per k-point for the full-width jitted SCF step, by the same rule
-#: (v5e compile rehearsal: 8.5 GiB at 2, 13.4 GiB at 4)
+#: (v5e compile rehearsal: 8.5 GiB at 2, 13.4 GiB at 4; on the 4-chip
+#: grids 9.0 GiB per chip at 2)
 NB_SCF = 2
-#: the full-width SCF runs until it converges or this many iterations
-SCF_ITERS = 60
+#: outer iterations of the full-width SCF.  With seed 0 its residual
+#: climbs for a dozen iterations before it falls (it converges in 55);
+#: 16 end below the start
+SCF_ITERS = 16
 SCF_INNER_STEPS = 2
-#: (n, d, bands per k) of the SCF iterations that --chips 4 compares
-#: across grids: half the Fig. 9 width, since the fused step compiles in
-#: ~3 min at full width and the comparison compiles it once per grid
-SCF4_WIDTH = (128, 64, 8)
+#: full-width SCF iterations that --chips 4 compares across grids
 SCF4_ITERS = 4
 #: paper Fig. 9 bands
 NB_PAPER = 256
@@ -95,8 +102,13 @@ def rel_err(got, ref) -> float:
 
 
 def peak_bytes(dev) -> int | None:
+    """Process-wide buffer high-water mark; blind to program temps."""
     stats = dev.memory_stats() or {}
     return stats.get("peak_bytes_in_use")
+
+
+def program_bytes(mem: dict) -> str:
+    return " ".join(f"{k}_bytes={v}" for k, v in mem.items())
 
 
 def timed_compile(fn, *args):
@@ -161,6 +173,7 @@ def stacked_input(inv, blocks, nb):
 def transform_run(grid, n, spheres, nb, blocks, label, dev, **axes):
     """Inverse then forward over the band batch; returns (psi rows, back)."""
     from repro.core import make_stacked_planewave_pair
+    from repro.obs.metrics import program_memory
 
     inv, fwd = make_stacked_planewave_pair(grid, n, spheres, nb, **axes)
     rows = np.array([0, nb])          # band 0 of each k-point
@@ -176,7 +189,8 @@ def transform_run(grid, n, spheres, nb, blocks, label, dev, **axes):
     (psi, back), t_steady = timed_call(compiled, x)
     say(f"transform[{label}] grid={grid.shape} n={n} d={spheres[0].extents[0]}"
         f" orbitals={host.shape[0]} compile_s={t_compile:.3f} "
-        f"steady_s={t_steady:.4f} peak_bytes={peak_bytes(dev)}")
+        f"steady_s={t_steady:.4f} {program_bytes(program_memory(compiled))} "
+        f"peak_bytes_in_use={peak_bytes(dev)}")
     return np.asarray(psi), np.asarray(back), host
 
 
@@ -223,11 +237,14 @@ def scf_run(label, cfg, grid):
             f"residual={resid:.6e}")
 
     res = run_scf(cfg, grid=grid, callback=report)
-    secs = ",".join(f"{r['seconds']:.3f}" for r in res.iteration_records)
+    secs = [r["seconds"] for r in res.iteration_records]
+    secs[0] -= res.compile_seconds       # the first iteration compiles
     say(f"scf[{label}] grid={res.grid_shape} n={cfg.n} nbands={cfg.nbands}"
         f" nk={len(cfg.kpts)} iterations={res.iterations} "
         f"converged={res.converged} energy={res.energy:.8f} "
-        f"compile_s={res.compile_seconds:.3f} iter_s=[{secs}]")
+        f"compile_s={res.compile_seconds:.3f} "
+        f"iter_s=[{','.join(f'{t:.3f}' for t in secs)}] "
+        f"{program_bytes(res.step_memory)}")
     check(np.all(np.isfinite(res.energies)), f"{label}: non-finite energy")
     return res
 
@@ -257,7 +274,7 @@ def phase_scf(seed, dev):
     from repro.configs.fftb_paper import CONFIG
     full = scf_run("full", scf_config(seed, CONFIG.n, CONFIG.diameter,
                                       NB_SCF), g1)
-    say(f"scf[full] peak_bytes={peak_bytes(dev)}")
+    say(f"scf[full] peak_bytes_in_use={peak_bytes(dev)}")
     check(full.residuals[-1] < full.residuals[0],
           f"full-width residual did not fall: {full.residuals}")
 
@@ -289,7 +306,8 @@ def phase_service(seed, dev):
     say(f"service n={n} d={d} requests={m['requests']} "
         f"dispatches={m['dispatches']} coalesced={m['coalesced_dispatches']}"
         f" serve_s={t_serve:.3f} max_rel_err_vs_eager={max(errs):.3e} "
-        f"tol={EAGER_RTOL:g} peak_bytes={peak_bytes(dev)}")
+        f"tol={EAGER_RTOL:g} peak_bytes_in_use={peak_bytes(dev)} "
+        f"(a dispatch runs several programs: no one memory_analysis)")
     say("service metrics " + json.dumps(m, sort_keys=True, default=str))
     check(m["requests"] == len(work), "service: not every request answered")
     check(all(np.isfinite(o).all() for o in outs), "service: non-finite")
@@ -316,6 +334,7 @@ def phase_pallas(seed, dev):
     from repro.dft import PlaneWaveBasis
     from repro.dft.hamiltonian import apply_hamiltonian_padded
     from repro.kernels import sphere_pack
+    from repro.obs.metrics import program_memory
 
     n, d, nb = largest_pallas_width()
     g1 = ProcGrid.create([1])
@@ -340,7 +359,8 @@ def phase_pallas(seed, dev):
         out[name], t_steady = timed_call(compiled, c_pad, v)
         say(f"pallas[{name}] n={n} d={d} nk={len(KPTS)} nbands={nb} "
             f"compile_s={t_compile:.3f} steady_s={t_steady:.4f} "
-            f"tpu_custom_call={custom} peak_bytes={peak_bytes(dev)}")
+            f"tpu_custom_call={custom} {program_bytes(program_memory(compiled))} "
+            f"peak_bytes_in_use={peak_bytes(dev)}")
         if name == "pallas":
             check(custom, "fused route: no tpu_custom_call in the HLO")
     fired = {k: sphere_pack.DISPATCHES[k] - d0[k] for k in d0}
@@ -367,10 +387,9 @@ def phase_four_chips(seed, devs):
                       {"batch_axes": (), "fft_axes": (0,)}),
              "batch2xfft2": (ProcGrid.create([2, 2], DFT_AXES_2D),
                              {"batch_axes": (0,), "fft_axes": (1,)})}
-    n4, d4, nb4 = SCF4_WIDTH
     say(f"cut: bands {NB_PAPER} -> {NB_TRANSFORM} per k-point x "
-        f"{len(KPTS)} k-points for the transform (n={n}, d={d} kept); "
-        f"SCF at n={n4}, d={d4}, {nb4} bands per k-point")
+        f"{len(KPTS)} k-points for the transform, {NB_SCF} per k-point "
+        f"for the SCF step (n={n}, d={d} kept)")
     spheres = [kpoint_sphere(d, k) for k in KPTS]
     blocks = random_blocks(np.random.default_rng(seed), spheres,
                            NB_TRANSFORM)
@@ -391,8 +410,8 @@ def phase_four_chips(seed, devs):
 
     energies = {}
     for label, (grid, axes) in grids.items():
-        res = scf_run(label, scf_config(seed, *SCF4_WIDTH, iters=SCF4_ITERS,
-                                        **axes), grid)
+        res = scf_run(label, scf_config(seed, n, d, NB_SCF,
+                                        iters=SCF4_ITERS, **axes), grid)
         energies[label] = np.asarray(res.energies)
         if label == "1chip":
             continue

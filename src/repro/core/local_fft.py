@@ -78,6 +78,20 @@ def dft_matrix_device(n_out: int, n_in: int, inverse: bool):
                 jnp.asarray(w.real + w.imag))
 
 
+def dft_operands(n_out: int, n_in: int, inverse: bool):
+    """``dft_matrix_device`` planes as GEMM operands, behind an
+    optimization barrier.
+
+    Under jit the planes are constants.  Where the other operand is known
+    too — the zero imaginary plane of a real field entering the Hartree
+    transform — XLA would constant-fold the whole GEMM in its evaluator at
+    compile time: ~40 s per 256³ line stage in a v5e compile.  The barrier
+    keeps the planes opaque, so the GEMM runs on the device instead.
+    """
+    return jax.lax.optimization_barrier(
+        dft_matrix_device(n_out, n_in, inverse))
+
+
 def _move_last(x, axis):
     return jnp.moveaxis(x, axis, -1)
 
@@ -98,7 +112,7 @@ def _jnp_backend(x, axis, n_in, n_out, inverse):
 
 
 def _matmul_backend(x, axis, n_in, n_out, inverse):
-    wr, wi, _ = dft_matrix_device(n_out, n_in, inverse)
+    wr, wi, _ = dft_operands(n_out, n_in, inverse)
     xm = _move_last(x, axis)
     xr, xi = jnp.real(xm), jnp.imag(xm)
     # y = x @ W^T with complex split into real MXU GEMMs

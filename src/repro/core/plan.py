@@ -497,15 +497,14 @@ class FftPlan(Plan):
         end-to-end, so nothing ever interleaves.  Same stages, same
         collectives — only the local data movement differs.
         """
-        from .local_fft import dft_matrix_device
+        from .local_fft import dft_operands
         perm = list(range(x.ndim))        # perm[i] = logical dim at pos i
         xr = jnp.real(x).astype(compute_dtype)
         xi = jnp.imag(x).astype(compute_dtype)
         for st in self.stages:
             if isinstance(st, FFTStage):
                 pos = perm.index(st.index)
-                wr, wi, ws = dft_matrix_device(st.n_out, st.n_in,
-                                               st.inverse)
+                wr, wi, ws = dft_operands(st.n_out, st.n_in, st.inverse)
                 wr = wr.astype(compute_dtype)
                 wi = wi.astype(compute_dtype)
                 dn = (((pos,), (1,)), ((), ()))

@@ -43,6 +43,7 @@ import jax.numpy as jnp
 
 from repro.core import ProcGrid, global_plan_cache
 from repro.core.policy import ExecPolicy
+from repro.obs.metrics import program_memory
 from repro.obs.trace import get_tracer
 
 from .basis import PlaneWaveBasis
@@ -238,8 +239,13 @@ class SCFResult:
                                       # realized per-segment padding, each
                                       # ≤ the configured segment_padding
     jitted: bool = False              # iterations ran as the fused jit step
-    compile_seconds: float = 0.0      # the fused step's trace + compile,
-                                      # outside ``seconds`` (0 when eager)
+    compile_seconds: float = 0.0      # the fused step's trace + compile
+                                      # (0 when eager); ``seconds`` and the
+                                      # first iteration record include it
+    step_memory: dict = dataclasses.field(default_factory=dict)
+                                      # the compiled step's
+                                      # ``memory_analysis()`` in bytes:
+                                      # argument, output, temp, code
     #: per-iteration telemetry: one dict per outer iteration with
     #: {iteration, energy, residual, seconds, transforms} — the record
     #: the observability layer attaches so a slow run can be broken
@@ -328,9 +334,13 @@ def make_scf_step(cfg: SCFConfig, basis, hartree, occ, nelec: float):
     ``step(rho, c_segs, mix_state, v_ext, coulomb, tables)`` → (ρ_next,
     c_segs', mix_state', ρ_out, eps per segment, energy, residual).  The
     cube-sized fields (``v_ext``, the Coulomb kernel) and the per-segment
-    band tables are arguments, not closed-over arrays, so the compiled
-    program does not carry them as constants.  Plans come from the
-    process-global PlanCache at trace time.
+    band tables are arguments, not closed-over arrays: the compiled
+    program does not carry them as constants (closed over, they add
+    ~37 MB to the serialized executable at n=128, ~8× that at n=256, and
+    to every compile-cache entry), and the step compiles from abstract
+    arguments for a chip that is only described
+    (``tests/test_tpu_compile.py``).  Plans come from the process-global
+    PlanCache at trace time.
     """
     segs = basis.segments
     inelec = 1.0 / max(nelec, 1e-9)
@@ -377,12 +387,13 @@ def _jit_scf_loop(cfg: SCFConfig, basis, v_ext, hartree, occ,
     Python work at all.  Plans and band tables come from the
     process-global PlanCache *eagerly at trace time*, which keeps cache
     traffic honestly accounted: one fetch per traced transform, zero per
-    steady-state iteration.  The step is compiled ahead of the loop, and
-    that compile is timed apart from the iterations.
+    steady-state iteration.  The step is compiled ahead of the loop; its
+    compile counts in ``seconds`` and in the first iteration, as a first
+    jitted call would, and is also returned apart.
 
     Returns (energies, residuals, records, eigs, ρ_out, transforms,
-    converged, seconds, compile_seconds) with the same accounting
-    semantics as the eager loop.
+    converged, seconds, compile_seconds, step_memory) with the same
+    accounting semantics as the eager loop.
     """
     segs = basis.segments
     invs = [basis.stacked_hamiltonian_plans(s)[0] for s in range(len(segs))]
@@ -395,11 +406,12 @@ def _jit_scf_loop(cfg: SCFConfig, basis, v_ext, hartree, occ,
               for s in range(len(segs)))
     mix_state = jit_mixer_init(basis.n ** 3, cfg.mix_history)
     consts = (v_ext, hartree.kernel, tables)
-    t_c = time.perf_counter()
+    t0 = time.perf_counter()
     step = jax.jit(make_scf_step(cfg, basis, hartree, occ, nelec),
                    donate_argnums=(0, 1, 2)
                    ).lower(rho, c_segs, mix_state, *consts).compile()
-    compile_seconds = time.perf_counter() - t_c
+    compile_seconds = time.perf_counter() - t0
+    step_memory = program_memory(step)
     # a compiled step takes exactly the placements it was compiled for;
     # on a multi-device grid its outputs may come back placed otherwise
     in_sh = step.input_shardings[0]
@@ -417,9 +429,8 @@ def _jit_scf_loop(cfg: SCFConfig, basis, v_ext, hartree, occ,
     per_iter = (2 + 2 * cfg.inner_steps * basis.nk * 2 * basis.nbands
                 + basis.nk * basis.nbands + 2)
     tr = get_tracer()
-    t0 = time.perf_counter()
     for it in range(cfg.max_iter):
-        it_t0 = time.perf_counter()
+        it_t0 = time.perf_counter() if it else t0
         with tr.span("scf_iteration", iteration=it, route="jit"):
             state = jax.device_put((rho, c_segs, mix_state), in_sh[:3])
             rho, c_segs, mix_state, rho_out, eps_segs, energy, resid = \
@@ -450,7 +461,7 @@ def _jit_scf_loop(cfg: SCFConfig, basis, v_ext, hartree, occ,
     jax.block_until_ready((rho, rho_out))
     seconds = time.perf_counter() - t0
     return energies, residuals, records, eigs, rho_out, transforms, \
-        converged, seconds, compile_seconds
+        converged, seconds, compile_seconds, step_memory
 
 
 def _init_coefficients(basis, seed: int):
@@ -511,10 +522,10 @@ def run_scf(cfg: SCFConfig, *, grid: ProcGrid | None = None,
 
     coeffs = _init_coefficients(basis, cfg.seed)
 
-    compile_seconds = 0.0
+    compile_seconds, step_memory = 0.0, {}
     if cfg.jit_step:
         (energies, residuals, iteration_records, eigs, rho, transforms,
-         converged, seconds, compile_seconds) = _jit_scf_loop(
+         converged, seconds, compile_seconds, step_memory) = _jit_scf_loop(
              cfg, basis, v_ext, hartree, occ, nelec, coeffs, callback)
     else:
         rho = density_from_orbitals(basis, coeffs, occ)
@@ -611,6 +622,7 @@ def run_scf(cfg: SCFConfig, *, grid: ProcGrid | None = None,
         backend=basis.backend,
         jitted=bool(cfg.jit_step),
         compile_seconds=compile_seconds,
+        step_memory=step_memory,
         segments=basis.nsegments,
         segment_padding_fractions=basis.segment_padding_fractions,
         iteration_records=iteration_records)

@@ -271,6 +271,20 @@ def register_weak_probe(registry: MetricsRegistry, name: str, obj,
 _GLOBAL = MetricsRegistry()
 
 
+def program_memory(compiled) -> dict:
+    """A compiled program's ``memory_analysis()`` in bytes: argument,
+    output, temp and generated code (empty where the backend has none).
+    Unlike the runtime's ``peak_bytes_in_use`` it counts the program's
+    temporaries."""
+    m = compiled.memory_analysis()
+    if m is None:
+        return {}
+    return {"argument": m.argument_size_in_bytes,
+            "output": m.output_size_in_bytes,
+            "temp": m.temp_size_in_bytes,
+            "code": m.generated_code_size_in_bytes}
+
+
 def global_metrics() -> MetricsRegistry:
     """The process-global registry bench records snapshot."""
     return _GLOBAL

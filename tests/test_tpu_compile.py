@@ -118,6 +118,46 @@ def test_fig9_line_dft_stage_compiles(one_chip, backend):
         assert "HIGHEST" in low.as_text()
 
 
+# ------------------------------------------------------ fused SCF step
+def test_scf_step_compiles_from_abstract_arguments(topo):
+    """The jitted SCF step takes its cube fields and band tables as
+    arguments, so it compiles for a chip that is only described — how
+    the chip smoke run's band counts are sized without a chip."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core import ProcGrid
+    from repro.core.compat import mesh_from_devices
+    from repro.dft import SCFConfig, StackedBandTables
+    from repro.dft.basis import PlaneWaveBasis
+    from repro.dft.hartree import HartreeSolver
+    from repro.dft.scf import jit_mixer_init, make_scf_step
+    from repro.sharding.grids import DFT_AXES_1D
+
+    n, d, nb = 16, 8, 2
+    grid = ProcGrid(mesh_from_devices(np.array(topo.devices[:1]),
+                                      DFT_AXES_1D), DFT_AXES_1D)
+    cfg = SCFConfig(n=n, diameter=d, nbands=nb, kpts=KPTS, stack_k=True,
+                    jit_step=True)
+    basis = PlaneWaveBasis(n, diameter=d, kpts=KPTS, nbands=nb, grid=grid)
+    width = basis.stacked_hamiltonian_plans(0)[0].npacked_max
+    rep = NamedSharding(grid.mesh, P())
+
+    def spec(shape, dtype=jnp.float32):
+        return _spec(rep, shape, dtype)
+
+    mix = jax.tree.map(lambda a: spec(a.shape, a.dtype), jax.eval_shape(
+        lambda: jit_mixer_init(n ** 3, cfg.mix_history)))
+    tables = (StackedBandTables(*(spec((len(KPTS), width)),) * 3),)
+    occ = np.full((len(KPTS), nb), 2.0)
+    step = make_scf_step(cfg, basis, HartreeSolver(basis), occ,
+                         float(occ.sum()))
+    c = _compile(step, spec((n,) * 3),
+                 (spec((len(KPTS), nb, width), jnp.complex64),), mix,
+                 spec((n,) * 3), spec((n,) * 3, jnp.complex64), tables)
+    assert c.memory_analysis().temp_size_in_bytes > 0
+
+
 # ---------------------------------------------- FFTB118 vs the compiler
 def test_fftb118_and_compiler_agree_at_smoke_width(one_chip):
     assert preflight_basis(PALLAS_N, diameter=PALLAS_D, kpts=KPTS,

@@ -51,7 +51,9 @@ def test_mixed_trace_matches_eager_bitwise(svc):
     """3 tenants × 2 sphere shapes, concurrent submits, against the eager
     oracle.  Concurrent submits change how the coalesced batches are
     composed, and with them the f32 GEMM rounding, so the contract is
-    ``EAGER_RTOL`` in the max norm rather than bitwise equality."""
+    ``EAGER_RTOL`` in the max norm rather than bitwise equality.  The
+    name predates that contract and is kept so the test's history stays
+    continuous."""
     rng = np.random.default_rng(0)
     veff = rng.standard_normal((N,) * 3).astype(np.float32)
     work = [("t0", _coeffs(rng, 2, SPH_G), SPH_G, veff),
