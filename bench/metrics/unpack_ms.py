@@ -1,0 +1,9 @@
+"""Device time of the operations under the program's ``fftb.unpack``
+scope per step (one batched round trip, or one SCF iteration), mean over
+the devices, in ms.  Read for ``unpack_ms.transform`` and
+``unpack_ms.scf`` alike."""
+from bench import scopes
+
+
+def read(tr, info):
+    return scopes.per_step_ms(tr, info, "fftb.unpack")

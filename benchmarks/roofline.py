@@ -14,16 +14,7 @@ available — rolled-scan records under-count loop bodies.
 MODEL_FLOPS = 6·N·D (dense) / 6·N_active·D (MoE); decode/prefill use the
 token count of the step (B·S for prefill, B for decode).
 
-**Trace mode** (``--trace FILE``): instead of dry-run artifacts, analyse
-a Chrome-trace JSON exported by the ``repro.obs`` tracer (per-stage plan
-spans, ``--trace-out`` on the examples/bench).  Move-stage spans carry
-the comm model's ``model_bytes_per_device`` tag, so measured all_to_all
-wall time divides into modeled bytes → the *effective* per-device link
-bandwidth each stage realized, next to the model's assumed peak — the
-measured-vs-modeled comm comparison, per stage.
-
 Run: PYTHONPATH=src python -m benchmarks.roofline [--mesh single]
-         [--trace trace.json]
 """
 from __future__ import annotations
 
@@ -93,51 +84,6 @@ def analyse(db: dict, mesh: str = "single"):
             "accounted": use_acct,
         })
     return rows
-
-
-def analyse_trace(trace: dict) -> dict:
-    """Measured-vs-modeled transform telemetry from a tracer export.
-
-    Aggregates the per-stage plan spans (``kind: fft`` line-DFT stages,
-    ``kind: a2a`` move stages) of a Chrome-trace JSON.  For each a2a
-    stage the modeled per-device bytes divide by measured wall seconds
-    into an effective link bandwidth; stages far below ``LINK`` are
-    latency- or layout-bound, not bandwidth-bound.
-    """
-    per_stage: dict[str, dict] = {}
-    fft_s = a2a_s = a2a_bytes = 0.0
-    for ev in trace.get("traceEvents", []):
-        if ev.get("ph") != "X":
-            continue
-        args = ev.get("args") or {}
-        kind = args.get("kind")
-        if kind not in ("fft", "a2a"):
-            continue
-        dur_s = float(ev.get("dur", 0.0)) * 1e-6
-        s = per_stage.setdefault(ev["name"], {
-            "kind": kind, "count": 0, "seconds": 0.0, "model_bytes": 0.0})
-        s["count"] += 1
-        s["seconds"] += dur_s
-        if kind == "a2a":
-            b = float(args.get("model_bytes_per_device", 0.0))
-            s["model_bytes"] += b
-            a2a_s += dur_s
-            a2a_bytes += b
-        else:
-            fft_s += dur_s
-    for s in per_stage.values():
-        if s["kind"] == "a2a" and s["seconds"] > 0:
-            s["effective_gbps"] = round(
-                s["model_bytes"] / s["seconds"] / 1e9, 3)
-    return {
-        "fft_seconds": round(fft_s, 6),
-        "a2a_seconds": round(a2a_s, 6),
-        "a2a_model_bytes": a2a_bytes,
-        "effective_link_gbps": round(
-            a2a_bytes / a2a_s / 1e9 if a2a_s else 0.0, 3),
-        "link_peak_gbps": LINK / 1e9,
-        "per_stage": per_stage,
-    }
 
 
 def _bytes_accessed(jitted, *args) -> float | None:
@@ -223,9 +169,6 @@ def main(argv=None):
     ap.add_argument("--mesh", default="single",
                     choices=["single", "multi"])
     ap.add_argument("--csv", default="")
-    ap.add_argument("--trace", default="", metavar="FILE",
-                    help="analyse a Chrome-trace JSON from the repro.obs "
-                         "tracer instead of the dry-run artifacts")
     ap.add_argument("--sphere-kernels", action="store_true",
                     help="report measured-vs-modeled bytes for the fused "
                          "sphere-pack pallas kernels against the composed "
@@ -249,22 +192,6 @@ def main(argv=None):
                      else f"{'n/a':>13s}")
                   + "   (bounding-cube write + read the fusion skips)")
         return rows
-    if args.trace:
-        with open(args.trace) as f:
-            rep = analyse_trace(json.load(f))
-        print(f"{'stage':28s} {'kind':5s} {'count':>6s} {'total_s':>10s} "
-              f"{'model_MiB':>10s} {'eff_GB/s':>9s}")
-        for name, s in sorted(rep["per_stage"].items()):
-            eff = s.get("effective_gbps")
-            print(f"{name:28s} {s['kind']:5s} {s['count']:6d} "
-                  f"{s['seconds']:10.4f} "
-                  f"{s['model_bytes'] / 2 ** 20:10.2f} "
-                  + (f"{eff:9.2f}" if eff is not None else f"{'—':>9s}"))
-        print(f"fft total {rep['fft_seconds']:.4f}s, "
-              f"a2a total {rep['a2a_seconds']:.4f}s, effective link "
-              f"{rep['effective_link_gbps']:.2f} GB/s "
-              f"(model peak {rep['link_peak_gbps']:.0f})")
-        return rep
     with open(RESULTS) as f:
         db = json.load(f)
     rows = analyse(db, args.mesh)

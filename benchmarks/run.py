@@ -655,12 +655,12 @@ def main(argv=None) -> None:
                          "benchmarks/baseline.json)")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write a Chrome-trace/Perfetto JSON of the run "
-                         "(per-stage spans with sync at span exit — "
-                         "perturbs timings, never gate a traced run)")
+                         "(plan spans with sync at span exit — perturbs "
+                         "timings, never gate a traced run)")
     args = ap.parse_args(argv)
     if args.trace_out:
         from repro.obs.trace import get_tracer
-        get_tracer().enable(sync=True, per_stage=True)
+        get_tracer().enable(sync=True)
     if args.scenarios == "all":
         wanted = set(SCENARIOS)
     elif args.scenarios == "gate":

@@ -12,7 +12,7 @@ Run:  PYTHONPATH=src python examples/planewave_dft.py \\
       (XLA_FLAGS=--xla_force_host_platform_device_count=4 to distribute;
        --grid auto picks 1D fft vs 2D batch×fft from the problem shape;
        --trace-out writes a Perfetto-loadable span trace — SCF iterations
-       nest transforms nest per-stage FFT/all_to_all spans)
+       nest band updates nest transform spans)
 """
 import argparse
 
@@ -90,11 +90,12 @@ def main(argv=None):
                          "--stack-k on to force it on small grids)")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write a Chrome-trace/Perfetto JSON of the run "
-                         "(per-stage plan spans, device-synced at span "
-                         "exit — slows the run, timings stay honest)")
+                         "(plan spans, device-synced at span exit — slows "
+                         "the run, timings stay honest; per-stage device "
+                         "time comes from a jax.profiler trace, by scope)")
     args = ap.parse_args(argv)
     if args.trace_out:
-        get_tracer().enable(sync=True, per_stage=True)
+        get_tracer().enable(sync=True)
 
     cfg = SCFConfig(
         n=args.n, diameter=args.diameter, nbands=args.bands,

@@ -14,8 +14,8 @@ Run:  PYTHONPATH=src python examples/serve_transforms.py \\
       (XLA_FLAGS=--xla_force_host_platform_device_count=4 with --grid 4
        to serve distributed transforms; d and n must divide the grid;
        --trace-out writes a Perfetto-loadable span trace — dispatch spans
-       nest transforms nest per-stage FFT/all_to_all, with per-request
-       queue-wait events on the side)
+       nest transform spans, with per-request queue-wait events on the
+       side)
 """
 import argparse
 import json
@@ -62,12 +62,13 @@ def main(argv=None):
     ap.add_argument("--max-rows", type=int, default=8)
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="write a Chrome-trace/Perfetto JSON of the run "
-                         "(per-stage plan spans, device-synced at span "
-                         "exit — slows the run, timings stay honest)")
+                         "(plan spans, device-synced at span exit — slows "
+                         "the run, timings stay honest; per-stage device "
+                         "time comes from a jax.profiler trace, by scope)")
     args = ap.parse_args(argv)
     d_small = args.d_small if args.d_small is not None else args.d // 2
     if args.trace_out:
-        get_tracer().enable(sync=True, per_stage=True)
+        get_tracer().enable(sync=True)
 
     grid = ProcGrid.create([args.grid], ["dft_f"])
     global_plan_cache().clear()

@@ -140,9 +140,9 @@ def realized_backend(n_in: int, n_out: int, backend: str) -> str:
     same single GEMM) requested above the ``MATMUL_MAX_N`` crossover
     *realizes* as "jnp" (the four-step factorization lives in
     ``kernels/ops.py`` and is not a line-stage backend).  Everything that
-    accounts or reports per-stage work — ``dft_flops``, stage spans,
-    ``describe()`` — must go through this so the books match what executed
-    rather than what was requested.
+    accounts or reports per-stage work — ``dft_flops``, ``describe()`` —
+    must go through this so the books match what executed rather than
+    what was requested.
     """
     if backend not in _BACKENDS:
         raise ValueError(f"unknown backend {backend!r}")

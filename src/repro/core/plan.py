@@ -22,6 +22,7 @@ instead of running a second schedule search.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import math
@@ -30,7 +31,6 @@ from functools import cached_property
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
 
 from . import compat
 from . import layout as L
@@ -39,6 +39,25 @@ from ..obs.trace import get_tracer
 from .dtensor import DistTensor
 from .local_fft import dft_flops, local_dft, realized_backend
 from .policy import TUNE_CANDIDATES, ExecPolicy
+
+
+#: names the plans give their work inside a compiled program (HLO
+#: ``op_name`` metadata, shown by any ``jax.profiler`` trace of the
+#: program); the sphere unpack/pack scopes live in ``planewave.py``
+LINE_DFT_SCOPE = "fftb.line_dft"
+A2A_SCOPE = "fftb.a2a"
+
+
+@contextlib.contextmanager
+def stage_scope(dim: str | None = None):
+    """``fftb.line_dft``, and inside it the stage's dim when given
+    (``fftb.line_dft/x``): metadata only, no change to the computation."""
+    with jax.named_scope(LINE_DFT_SCOPE):
+        if dim is None:
+            yield
+        else:
+            with jax.named_scope(dim):
+                yield
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,8 +70,9 @@ class FFTStage:
     backend: str
 
     def apply(self, x):
-        return local_dft(x, self.index, self.n_out, inverse=self.inverse,
-                         backend=self.backend)
+        with stage_scope(self.dim):
+            return local_dft(x, self.index, self.n_out,
+                             inverse=self.inverse, backend=self.backend)
 
     def mirrored(self) -> "FFTStage":
         """The stage of the derived inverse/adjoint plan.
@@ -88,9 +108,10 @@ class MoveStage:
     dst_index: int
 
     def apply(self, x):
-        return jax.lax.all_to_all(
-            x, self.axis_name, split_axis=self.dst_index,
-            concat_axis=self.src_index, tiled=True)
+        with jax.named_scope(A2A_SCOPE):
+            return jax.lax.all_to_all(
+                x, self.axis_name, split_axis=self.dst_index,
+                concat_axis=self.src_index, tiled=True)
 
     def mirrored(self) -> "MoveStage":
         """The opposite distributed transpose (all_to_all is a permutation,
@@ -215,7 +236,7 @@ class Plan:
 
     # ---------------------------------------------------------- accounting
     def private_bytes(self) -> int:
-        """Bytes owned by this plan alone — descriptors, traced executors,
+        """Bytes owned by this plan alone — descriptors, jitted executors,
         and (in subclasses) the sphere pack/mask or ragged-batch tables.
         Never shared with other plans, so the cache bills them per entry."""
         return 4096
@@ -482,7 +503,8 @@ class FftPlan(Plan):
         for st in self.stages:
             x = st.apply(x)
         if self.scale != 1.0:
-            x = x * jnp.asarray(self.scale, x.dtype)
+            with stage_scope():
+                x = x * jnp.asarray(self.scale, x.dtype)
         return x
 
     def _raw_apply_lazy(self, x, compute_dtype=jnp.float32):
@@ -499,14 +521,13 @@ class FftPlan(Plan):
         """
         from .local_fft import dft_operands
         perm = list(range(x.ndim))        # perm[i] = logical dim at pos i
-        xr = jnp.real(x).astype(compute_dtype)
-        xi = jnp.imag(x).astype(compute_dtype)
+        with stage_scope():
+            xr = jnp.real(x).astype(compute_dtype)
+            xi = jnp.imag(x).astype(compute_dtype)
         for st in self.stages:
             if isinstance(st, FFTStage):
                 pos = perm.index(st.index)
                 wr, wi, ws = dft_operands(st.n_out, st.n_in, st.inverse)
-                wr = wr.astype(compute_dtype)
-                wi = wi.astype(compute_dtype)
                 dn = (((pos,), (1,)), ((), ()))
 
                 def dot(a, b):
@@ -516,29 +537,35 @@ class FftPlan(Plan):
                 # instead of 4 (−25% MXU work and operand traffic):
                 #   m1 = xr·wr, m2 = xi·wi, m3 = (xr+xi)·(wr+wi)
                 #   yr = m1 − m2, yi = m3 − m1 − m2
-                m1 = dot(xr, wr)
-                m2 = dot(xi, wi)
-                m3 = dot((xr + xi).astype(compute_dtype),
-                         ws.astype(compute_dtype))
-                xr = (m1 - m2).astype(compute_dtype)
-                xi = (m3 - m1 - m2).astype(compute_dtype)
+                with stage_scope(st.dim):
+                    wr = wr.astype(compute_dtype)
+                    wi = wi.astype(compute_dtype)
+                    m1 = dot(xr, wr)
+                    m2 = dot(xi, wi)
+                    m3 = dot((xr + xi).astype(compute_dtype),
+                             ws.astype(compute_dtype))
+                    xr = (m1 - m2).astype(compute_dtype)
+                    xi = (m3 - m1 - m2).astype(compute_dtype)
                 perm = [p for i, p in enumerate(perm) if i != pos] \
                     + [st.index]
             else:
                 sp = perm.index(st.dst_index)
                 cp = perm.index(st.src_index)
-                xr = jax.lax.all_to_all(xr, st.axis_name, split_axis=sp,
-                                        concat_axis=cp, tiled=True)
-                xi = jax.lax.all_to_all(xi, st.axis_name, split_axis=sp,
-                                        concat_axis=cp, tiled=True)
+                with jax.named_scope(A2A_SCOPE):
+                    xr = jax.lax.all_to_all(xr, st.axis_name, split_axis=sp,
+                                            concat_axis=cp, tiled=True)
+                    xi = jax.lax.all_to_all(xi, st.axis_name, split_axis=sp,
+                                            concat_axis=cp, tiled=True)
         out_axes = [perm.index(i) for i in range(len(perm))]
-        xr = jnp.transpose(xr, out_axes)
-        xi = jnp.transpose(xi, out_axes)
-        if self.scale != 1.0:
-            s = jnp.asarray(self.scale, jnp.float32)
-            xr, xi = xr.astype(jnp.float32) * s, xi.astype(jnp.float32) * s
-        return jax.lax.complex(xr.astype(jnp.float32),
-                               xi.astype(jnp.float32))
+        with stage_scope():
+            xr = jnp.transpose(xr, out_axes)
+            xi = jnp.transpose(xi, out_axes)
+            if self.scale != 1.0:
+                s = jnp.asarray(self.scale, jnp.float32)
+                xr = xr.astype(jnp.float32) * s
+                xi = xi.astype(jnp.float32) * s
+            return jax.lax.complex(xr.astype(jnp.float32),
+                                   xi.astype(jnp.float32))
 
     def _sharded(self, pol: ExecPolicy):
         mesh = self.grid.mesh
@@ -571,79 +598,13 @@ class FftPlan(Plan):
         FftPlan.executions += 1
         return self._fn_for(pol)(x)
 
-    # -------------------------------------------------- traced execution
-    def _pspec_for_layout(self, lay) -> P:
-        """PartitionSpec of this plan's dims under layout ``lay`` —
-        the same rendering ``DistTensor.pspec`` does, for the
-        *intermediate* layouts between stages."""
-        entries = []
-        for d in self.dims:
-            axes = lay.get(d, ())
-            if not axes:
-                entries.append(None)
-            elif len(axes) == 1:
-                entries.append(self.grid.axis_name(axes[0]))
-            else:
-                entries.append(tuple(self.grid.axis_name(a) for a in axes))
-        return P(*entries)
-
-    @cached_property
-    def _stage_executors(self):
-        """One jitted ``shard_map`` per stage, with span metadata.
-
-        The normal executor is ONE ``jit(shard_map(...))`` over the whole
-        stage list — individual stages cannot be timed inside it.  When
-        per-stage tracing is on, execution runs stage-by-stage instead:
-        each stage gets its own small sharded callable whose in/out
-        PartitionSpecs come from replaying the layout moves (exactly as
-        ``_comm_stats_for`` prices them), and MoveStage spans carry the
-        comm model's ``bytes_per_device``/``procs`` tags so traces hold
-        measured *and* modeled comm side by side.
-        """
-        mesh = self.grid.mesh
-        lay = L.normalize(self.tin.layout)
-        grid_shape = self.grid.shape
-        comm = iter(self.comm_stats())
-        out = []
-        for st in self.stages:
-            in_spec = self._pspec_for_layout(lay)
-            if isinstance(st, FFTStage):
-                kind = "idft" if st.inverse else "dft"
-                meta = {"name": f"{kind}[{st.dim}] {st.n_in}->{st.n_out}",
-                        "kind": "fft", "backend": st.realized_backend}
-                out_spec = in_spec
-            else:
-                stats = next(comm)
-                ax = [a for a in range(len(grid_shape))
-                      if self.grid.axis_name(a) == st.axis_name][0]
-                lay = L.apply_move(lay, L.Move(ax, st.src, st.dst))
-                out_spec = self._pspec_for_layout(lay)
-                meta = {"name": f"a2a[{st.axis_name}] {st.src}->{st.dst}",
-                        "kind": "a2a", "procs": stats["procs"],
-                        "model_bytes_per_device":
-                            stats["bytes_per_device"]}
-            fn = jax.jit(compat.shard_map(st.apply, mesh, in_spec,
-                                          out_spec))
-            out.append((fn, meta))
-        return out
-
     def _execute_traced(self, x, pol: ExecPolicy, tr):
         FftPlan.executions += 1
         name = ("ifft" if self.is_inverse else "fft") \
             + f"{len(self.fft_pairs)}d"
         with tr.span(f"plan:{name}", shape=list(self.tin.shape),
                      mode=pol.mode, stages=len(self.stages)) as sp:
-            if not tr.per_stage:
-                return sp.sync(self._fn_for(pol)(x))
-            # stage-by-stage: eager per-stage apply (the lazy executor
-            # interleaves stages and cannot be split), one span each
-            for fn, meta in self._stage_executors:
-                attrs = {k: v for k, v in meta.items() if k != "name"}
-                with tr.span(meta["name"], **attrs) as ssp:
-                    x = ssp.sync(fn(x))
-            if self.scale != 1.0:
-                x = x * jnp.asarray(self.scale, x.dtype)
-            return sp.sync(x)
+            return sp.sync(self._fn_for(pol)(x))
 
 
 global_metrics().register_probe(

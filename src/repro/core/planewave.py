@@ -41,6 +41,11 @@ from .local_fft import dft_matrix_device, realized_backend
 from .plan import FFTStage, FftPlan, Plan
 from .policy import ExecPolicy
 
+#: the names the sphere scatter and gather carry inside a compiled
+#: program, composed route and fused kernels alike (metadata only)
+UNPACK_SCOPE = "fftb.unpack"
+PACK_SCOPE = "fftb.pack"
+
 
 # ---------------------------------------------------------- fused kernels
 def _pspec_entry(grid, axes):
@@ -232,7 +237,8 @@ class _FusedTransformMixin:
                          npacked=parts["in_shape"][1]) as sp:
                 mid = sp.sync(parts["fn"](packed, *parts["tables"]))
         else:
-            mid = parts["fn"](packed, *parts["tables"])
+            with jax.named_scope(UNPACK_SCOPE):
+                mid = parts["fn"](packed, *parts["tables"])
         return parts["rem"](mid, policy=pol)
 
     def transform_pack(self, cube, *, policy: ExecPolicy | None = None):
@@ -249,7 +255,8 @@ class _FusedTransformMixin:
             with tr.span("fused:dft_pack", backend="pallas",
                          npacked=parts["out_shape"][1]) as sp:
                 return sp.sync(parts["fn"](mid, *parts["tables"]))
-        return parts["fn"](mid, *parts["tables"])
+        with jax.named_scope(PACK_SCOPE):
+            return parts["fn"](mid, *parts["tables"])
 
     def _fused_table_bytes(self) -> int:
         tot = 0
@@ -292,8 +299,8 @@ class PlaneWaveFFT(_FusedTransformMixin, Plan):
         return self.plan._execute(x, pol)
 
     def _execute_traced(self, x, pol: ExecPolicy, tr):
-        # wrap the inner plan's (possibly per-stage) spans in one
-        # transform-level span tagged with the sphere shape
+        # wrap the inner plan's span in one transform-level span tagged
+        # with the sphere shape
         with tr.span("planewave", inverse=self.is_inverse,
                      d=self.sphere.extents[0], n=self.n[0]) as sp:
             return sp.sync(self.plan._execute_traced(x, pol, tr))
@@ -329,15 +336,18 @@ class PlaneWaveFFT(_FusedTransformMixin, Plan):
     def unpack(self, packed):
         """(…, npacked) CSR coefficients → (…, d, d, d) bounding cube."""
         d = self.sphere.extents
-        flat = jnp.zeros(packed.shape[:-1] + (math.prod(d),), packed.dtype)
-        flat = flat.at[..., self._pack_idx].set(packed)
-        return flat.reshape(packed.shape[:-1] + d)
+        with jax.named_scope(UNPACK_SCOPE):
+            flat = jnp.zeros(packed.shape[:-1] + (math.prod(d),),
+                             packed.dtype)
+            flat = flat.at[..., self._pack_idx].set(packed)
+            return flat.reshape(packed.shape[:-1] + d)
 
     def pack(self, cube):
         """(…, d, d, d) bounding cube → (…, npacked) CSR coefficients."""
         d = self.sphere.extents
-        flat = cube.reshape(cube.shape[:-3] + (math.prod(d),))
-        return flat[..., self._pack_idx]
+        with jax.named_scope(PACK_SCOPE):
+            flat = cube.reshape(cube.shape[:-3] + (math.prod(d),))
+            return flat[..., self._pack_idx]
 
     def mask_cube(self, cube):
         """Zero out everything outside the cut-off sphere (cube form)."""
@@ -628,7 +638,7 @@ class StackedPlaneWaveFFT(_FusedTransformMixin, Plan):
     fraction, not correctness.
 
     The inner ``FftPlan`` is the same d³→n³ stacked plan the density build
-    uses (pass it via ``plan=`` to share the cached object and its traced
+    uses (pass it via ``plan=`` to share the cached object and its jitted
     executors); this class adds the ragged-batch bookkeeping.
     """
 
@@ -759,12 +769,13 @@ class StackedPlaneWaveFFT(_FusedTransformMixin, Plan):
         """
         d = self.extents
         cells = math.prod(d)
-        c = padded.reshape(self.nk, self.nbands, self.npacked_max)
-        flat = jnp.zeros((self.nk, self.nbands, cells + 1), padded.dtype)
-        kk = jnp.arange(self.nk)[:, None, None]
-        bb = jnp.arange(self.nbands)[None, :, None]
-        flat = flat.at[kk, bb, self._pad_idx[:, None, :]].set(c)
-        return flat[..., :cells].reshape((self.nk * self.nbands,) + d)
+        with jax.named_scope(UNPACK_SCOPE):
+            c = padded.reshape(self.nk, self.nbands, self.npacked_max)
+            flat = jnp.zeros((self.nk, self.nbands, cells + 1), padded.dtype)
+            kk = jnp.arange(self.nk)[:, None, None]
+            bb = jnp.arange(self.nbands)[None, :, None]
+            flat = flat.at[kk, bb, self._pad_idx[:, None, :]].set(c)
+            return flat[..., :cells].reshape((self.nk * self.nbands,) + d)
 
     def pack(self, cube):
         """``(nk·nbands, d, d, d)`` cubes → ``(nk·nbands, npacked_max)``.
@@ -777,14 +788,16 @@ class StackedPlaneWaveFFT(_FusedTransformMixin, Plan):
         """
         d = self.extents
         cells = math.prod(d)
-        flat = cube.reshape(self.nk, self.nbands, cells)
-        # take_along_axis keeps the gather single-indexed: no per-dispatch
-        # start-index concatenate in the lowered computation
-        idx = jnp.broadcast_to(self._pack_gather_idx[:, None, :],
-                               (self.nk, self.nbands, self.npacked_max))
-        out = jnp.take_along_axis(flat, idx, axis=2)
-        out = jnp.where(self._valid_dev[:, None, :], out, 0)
-        return out.reshape(self.nk * self.nbands, self.npacked_max)
+        with jax.named_scope(PACK_SCOPE):
+            flat = cube.reshape(self.nk, self.nbands, cells)
+            # take_along_axis keeps the gather single-indexed: no
+            # per-dispatch start-index concatenate in the lowered
+            # computation
+            idx = jnp.broadcast_to(self._pack_gather_idx[:, None, :],
+                                   (self.nk, self.nbands, self.npacked_max))
+            out = jnp.take_along_axis(flat, idx, axis=2)
+            out = jnp.where(self._valid_dev[:, None, :], out, 0)
+            return out.reshape(self.nk * self.nbands, self.npacked_max)
 
     # ------------------------------------------------------- fused kernels
     @property

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 
@@ -44,12 +45,14 @@ def density_from_stacked(basis, c_pad, occ, seg: int = 0) -> jnp.ndarray:
     """
     inv, _ = basis.stacked_hamiltonian_plans(seg)
     nks, nb, npm = c_pad.shape
-    psi = inv(inv.unpack(c_pad.reshape(nks * nb, npm)))
     idx = list(basis.segments[seg])
     w = (basis.weights[idx, None] * np.asarray(occ, np.float64)[idx]
          ).reshape(-1).astype(np.float32)
-    rho = jnp.tensordot(jnp.asarray(w), jnp.abs(psi) ** 2, axes=(0, 0))
-    return rho * jnp.float32(basis.n ** 3 / basis.dv)
+    with jax.named_scope("scf.density"):
+        psi = inv(inv.unpack(c_pad.reshape(nks * nb, npm)))
+        rho = jnp.tensordot(jnp.asarray(w), jnp.abs(psi) ** 2,
+                            axes=(0, 0))
+        return rho * jnp.float32(basis.n ** 3 / basis.dv)
 
 
 def _density_stacked(basis, coeffs, occ) -> jnp.ndarray:

@@ -41,6 +41,7 @@ Two band-update engines share that math:
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.obs.metrics import global_metrics
@@ -53,6 +54,10 @@ PERK_LINALG_CALLS = 0
 
 global_metrics().register_probe(
     "dft", lambda: {"per_k_linalg_calls": PERK_LINALG_CALLS})
+
+#: the band update's dense linalg (descent directions, Gram, ``eigh``,
+#: QR, Rayleigh-Ritz) as a compiled program names it
+SUBSPACE_SCOPE = "scf.subspace"
 
 
 def _replicated(basis, x):
@@ -145,9 +150,10 @@ def apply_hamiltonian_padded(basis, c_pad, v_eff, kin_pad=None,
         kin_pad = basis.stacked_band_tables(seg).kinetic
     inv, fwd = basis.stacked_hamiltonian_plans(seg)
     nk, nb, npm = c_pad.shape
-    psi = inv.unpack_transform(c_pad.reshape(nk * nb, npm))
-    vc = fwd.transform_pack(psi * v_eff).reshape(nk, nb, npm)
-    return kin_pad[:, None, :] * c_pad + vc
+    with jax.named_scope("scf.hamiltonian"):
+        psi = inv.unpack_transform(c_pad.reshape(nk * nb, npm))
+        vc = fwd.transform_pack(psi * v_eff).reshape(nk, nb, npm)
+        return kin_pad[:, None, :] * c_pad + vc
 
 
 def apply_hamiltonian_stacked(basis, blocks, v_eff):
@@ -358,11 +364,13 @@ def update_bands_stacked(basis, c_pad, v_eff, *, steps: int = 3,
         hc = _replicated(basis, apply_hamiltonian_padded(basis, c, v_eff,
                                                          kin, seg=seg))
         nsweep += 1
-        d = _replicated(basis, _descent_direction_stacked(c, hc, pre))
+        with jax.named_scope(SUBSPACE_SCOPE):
+            d = _replicated(basis, _descent_direction_stacked(c, hc, pre))
         hd = _replicated(basis, apply_hamiltonian_padded(basis, d, v_eff,
                                                          kin, seg=seg))
         nsweep += 1
-        c, eps = _rayleigh_ritz_stacked(c, d, hc, hd)
+        with jax.named_scope(SUBSPACE_SCOPE):
+            c, eps = _rayleigh_ritz_stacked(c, d, hc, hd)
     return c, eps, nsweep
 
 

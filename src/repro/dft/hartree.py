@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 
@@ -40,9 +41,10 @@ class HartreeSolver:
         passes the Coulomb kernel as an argument.
         """
         fwd, inv = self.basis.cube_plans()
-        rho_g = fwd(rho.astype(jnp.complex64))
-        kern = self.kernel if kernel is None else kernel
-        return jnp.real(inv(rho_g * kern))
+        with jax.named_scope("scf.hartree"):
+            rho_g = fwd(rho.astype(jnp.complex64))
+            kern = self.kernel if kernel is None else kernel
+            return jnp.real(inv(rho_g * kern))
 
     def energy(self, rho, vh) -> float:
         """E_H = ½ ∫ ρ v_H  (discretized with ΔV)."""

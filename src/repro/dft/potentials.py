@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 #: Slater exchange constant C_x = (3/4)(3/π)^{1/3}
@@ -41,8 +42,9 @@ def lda_exchange(rho):
     v_x = δE_x/δρ = −(4/3) C_x ρ^{1/3}.  ρ is clipped at 0 — it is a sum
     of |ψ|² terms, so negatives are only mixing artifacts.
     """
-    r = jnp.maximum(rho, 0.0)
-    r13 = jnp.cbrt(r)
-    e_x = -_CX * r13 * r
-    v_x = -(4.0 / 3.0) * _CX * r13
-    return e_x, v_x
+    with jax.named_scope("scf.xc"):
+        r = jnp.maximum(rho, 0.0)
+        r13 = jnp.cbrt(r)
+        e_x = -_CX * r13 * r
+        v_x = -(4.0 / 3.0) * _CX * r13
+        return e_x, v_x

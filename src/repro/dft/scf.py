@@ -361,14 +361,17 @@ def make_scf_step(cfg: SCFConfig, basis, hartree, occ, nelec: float):
         c_new = tuple(c_new)
         rho_out = sum(density_from_stacked(basis, c_new[s], occ, seg=s)
                       for s in range(len(segs)))
-        energy = total_energy_stacked(
-            basis, c_new, rho_out, v_ext,
-            lambda r: hartree(r, coulomb), occ, xc=cfg.xc, tables=tables)
+        with jax.named_scope("scf.energy"):
+            energy = total_energy_stacked(
+                basis, c_new, rho_out, v_ext,
+                lambda r: hartree(r, coulomb), occ, xc=cfg.xc,
+                tables=tables)
         resid = (jnp.linalg.norm(rho_out - rho)
                  * jnp.float32(basis.dv ** 0.5 * inelec))
-        mix_state, rho_next = jit_mix(mix_state, rho, rho_out,
-                                      alpha=cfg.mix_alpha,
-                                      warmup=cfg.mix_warmup)
+        with jax.named_scope("scf.mixer"):
+            mix_state, rho_next = jit_mix(mix_state, rho, rho_out,
+                                          alpha=cfg.mix_alpha,
+                                          warmup=cfg.mix_warmup)
         return (rho_next, c_new, mix_state, rho_out,
                 tuple(eps_segs), energy, resid)
 

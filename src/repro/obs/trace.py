@@ -16,6 +16,13 @@ a bounded ring buffer.  The design constraints, in order:
 * **Threads nest independently.**  Each thread has its own span stack;
   depth and parent are per-thread, and exported events carry a per-thread
   track id so Perfetto renders one lane per thread.
+* **One clock with the device.**  An enabled span also opens a
+  ``jax.profiler.TraceAnnotation`` of its name, so a ``jax.profiler``
+  trace shows it on the host plane, on the clock the device's operations
+  are aligned to.  Device time per layer comes from such a trace of the
+  real compiled program, by the ``jax.named_scope`` names the layers give
+  their work (``fftb.unpack``, ``fftb.line_dft/<dim>``, ``scf.hartree``,
+  …); a span under ``jit`` tracing times nothing and is never opened.
 
 Export is the Chrome trace event format (``ph: "X"`` complete events,
 timestamps in microseconds) — load the JSON in Perfetto
@@ -75,7 +82,7 @@ class Span:
     """One live span: a context manager that records itself on exit."""
 
     __slots__ = ("_tracer", "name", "attrs", "t0", "t1", "depth", "parent",
-                 "_sync_value", "_tid")
+                 "_sync_value", "_tid", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         self._tracer = tracer
@@ -86,6 +93,7 @@ class Span:
         self.parent = None
         self._sync_value = None
         self._tid = None
+        self._annotation = None
 
     def set(self, **attrs):
         """Attach attributes after entry (e.g. results known at exit)."""
@@ -107,6 +115,8 @@ class Span:
         self.parent = stack[-1].name if stack else None
         self._tid = threading.get_ident()
         stack.append(self)
+        self._annotation = self._tracer._annotate(self.name)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -116,6 +126,8 @@ class Span:
             jax.block_until_ready(self._sync_value)
             self._sync_value = None
         self.t1 = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
+        self._annotation = None
         stack = self._tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -130,7 +142,7 @@ class Tracer:
     def __init__(self, max_events: int = 200_000):
         self.enabled = False
         self.sync = True          # block_until_ready at span exit
-        self.per_stage = True     # plans execute stage-by-stage when traced
+        self._annotate = None     # jax.profiler.TraceAnnotation, once on
         self._events: deque = deque(maxlen=max_events)
         self.dropped = 0
         self._local = threading.local()
@@ -138,15 +150,15 @@ class Tracer:
         self._origin = time.perf_counter()
 
     # ------------------------------------------------------------ lifecycle
-    def enable(self, *, sync: bool = True, per_stage: bool = True,
-               clear: bool = True) -> "Tracer":
+    def enable(self, *, sync: bool = True, clear: bool = True) -> "Tracer":
         """Start recording.  ``sync`` blocks on marked values at span exit
-        (honest device timing); ``per_stage`` asks plans to execute
-        stage-by-stage so FFT vs all_to_all get separate spans."""
+        (honest device timing)."""
+        from jax.profiler import TraceAnnotation
+
         if clear:
             self.clear()
         self.sync = bool(sync)
-        self.per_stage = bool(per_stage)
+        self._annotate = TraceAnnotation
         self.enabled = True
         return self
 

@@ -21,7 +21,7 @@ by the pack tables — so a mixed-tenant batch is exactly two distributed
 transforms, like a single big one.  Row counts are **bucketed** to the
 next power of two (capped at ``max_rows``, short rows filled with inert
 zero-coefficient repeats of the first sphere), so the inner d³→n³
-``FftPlan`` — and its traced executors — are shared across every batch
+``FftPlan`` — and its jitted executors — are shared across every batch
 composition of a bucket; only the cheap pack-table wrapper is
 per-composition.  Both layers live in the (by default process-global)
 ``PlanCache``: the wrapper entries churn through byte-weighted eviction,
