@@ -7,7 +7,7 @@ cut-off sphere onto the xy-plane, as in Quantum Espresso).
 
 All index bookkeeping here is static numpy executed at *plan build time* —
 nothing in this module is traced by JAX.  The offset arrays are turned into
-static gather/scatter index tables used by the pack/unpack stages.
+static index tables used by the pack/unpack stages.
 """
 from __future__ import annotations
 
@@ -130,8 +130,8 @@ class SphereDomain(Domain):
     # ------------------------------------------------- static index tables
     def pack_indices(self) -> np.ndarray:
         """Flat indices into the bounding cuboid (x, y, z C-order) for every
-        packed coefficient, in CSR order.  Used by unpack (scatter) / pack
-        (gather) stages; built once per plan."""
+        packed coefficient, in CSR order.  Used by the pack gather and the
+        unpack's line tables; built once per plan."""
         ex, ey, ez = self.extents
         (xl, yl, zl) = self.lower
         lens = (self._z_hi - self._z_lo).astype(np.int64)

@@ -25,6 +25,7 @@ pair costs one schedule search, not two.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as np
@@ -41,7 +42,7 @@ from .local_fft import dft_matrix_device, realized_backend
 from .plan import FFTStage, FftPlan, Plan
 from .policy import ExecPolicy
 
-#: the names the sphere scatter and gather carry inside a compiled
+#: the names the sphere unpack and pack carry inside a compiled
 #: program, composed route and fused kernels alike (metadata only)
 UNPACK_SCOPE = "fftb.unpack"
 PACK_SCOPE = "fftb.pack"
@@ -267,6 +268,103 @@ class _FusedTransformMixin:
         return tot
 
 
+# ------------------------------------------------------ line-window unpack
+#: widest piece the line-window unpack gathers whole: one vreg row
+_ROW = 128
+
+
+def line_window_tables(spheres) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Host tables of the line-window unpack, one row per sphere.
+
+    For every bounding-box line ``l = x·ey + y``: ``off[k, l]``, the first
+    lane of the ``ez``-wide window that holds the line in the packed row
+    padded with ``ez`` zeros on each side (an empty line points at the
+    front pad), and the line's z range ``[lo[k, l], hi[k, l])``.  Built
+    from ``sphere_pack.line_tables``; each is ``(nk, ex·ey)`` int32.
+    """
+    from ..kernels import sphere_pack
+    start, zlo, cnt, _ = sphere_pack.line_tables(spheres, 1)
+    ez = spheres[0].extents[2]
+    off = np.where(cnt > 0, start - zlo + ez, 0).astype(np.int32)
+    return off, zlo, zlo + cnt
+
+
+def unpack_lines(c, ks, off, lo, hi, extents):
+    """``(B, npacked)`` CSR rows → ``(B, ex, ey, ez)`` cubes; row ``r``
+    holds sphere ``ks[r]``'s coefficients.
+
+    Moves whole z-lines: every line of sphere k is the ``ez``-wide window
+    at ``off`` (:func:`line_window_tables`) of its zero-padded packed
+    rows.  The rows are cut into pieces of ``ez`` lanes rounded up to a
+    power of two, at most ``_ROW``, and each line gathers the whole
+    pieces its window touches (one index a piece); a barrel shift, one
+    select a bit of the window's offset in its first piece, then lines
+    the window up with z.  A last select keeps ``lo ≤ z < hi`` and writes
+    +0.0 elsewhere, so lanes past a sphere's ``npacked`` never reach the
+    cube whatever they hold.  Pure data movement: bit for bit the element
+    scatter of the packed lanes into a zero cube.
+
+    One band a loop step: gathering every band's windows at once holds
+    them all (2.5 GiB of temporaries at d=128 and 32 bands) and runs half
+    as fast on a TPU v5e.
+    """
+    batch, npk = c.shape
+    ez = extents[-1]
+    piece = min(_ROW, 1 << (ez - 1).bit_length())
+    nrow = -(-ez // piece) + 1           # pieces any ez-wide window spans
+    rows = (npk + ez) // piece + nrow
+    c = jnp.pad(c, ((0, 0), (ez, rows * piece - npk - ez)))
+    first = jnp.asarray((off // piece)[:, :, None]
+                        + np.arange(nrow, dtype=np.int32))
+    rot = jnp.asarray(off % piece)[:, :, None]
+    lo, hi = jnp.asarray(lo)[:, :, None], jnp.asarray(hi)[:, :, None]
+    z = jnp.arange(ez, dtype=jnp.int32)
+
+    def band(args):
+        row, k = args                     # (rows, piece), its sphere
+        win = row[first[k]].reshape(-1, nrow * piece)   # (ex·ey, lanes)
+        bit = 1
+        while bit < piece:                # win[:, z] ← win[:, z + rot]
+            width = win.shape[-1] - bit
+            win = jnp.where(rot[k] & bit != 0, win[:, bit:], win[:, :width])
+            bit *= 2
+        return jnp.where((z >= lo[k]) & (z < hi[k]), win[:, :ez], 0)
+
+    cube = jax.lax.map(band, (c.reshape(batch, rows, piece), ks))
+    return cube.reshape((batch,) + tuple(extents))
+
+
+def _unpack_program(tin, tables, extents):
+    """:func:`unpack_lines` over one plan's tables, jitted, for
+    ``(B, npacked)`` rows in k-blocks of ``B / nk`` bands.
+
+    The loop runs over the batch, so where the plan's batch dims ride grid
+    axes it runs inside a ``shard_map`` over them: each shard unpacks its
+    own bands (a loop over a sharded dim would gather the whole batch onto
+    every device).  Jitted so that an eager call compiles its loop once a
+    shape, not once a call; under a caller's jit the tables stay constants
+    of the program.
+    """
+    off, lo, hi = tables
+    nk = off.shape[0]
+    body = functools.partial(unpack_lines, off=off, lo=lo, hi=hi,
+                             extents=tuple(extents))
+    grid = tin.grid
+    axes = tuple(a for dim in tin.dims[:-3] for a in tin.layout.get(dim, ()))
+    shards = math.prod(grid.axis_size(a) for a in axes)
+    bentry = _pspec_entry(grid, axes)
+
+    def run(c):
+        ks = np.repeat(np.arange(nk, dtype=np.int32), c.shape[0] // nk)
+        if shards == 1 or c.shape[0] % shards:
+            return body(c, ks)
+        fn = compat.shard_map(body, grid.mesh, (P(bentry, None), P(bentry)),
+                              P(bentry, None, None, None))
+        return fn(c, ks)
+
+    return jax.jit(run)
+
+
 class PlaneWaveFFT(_FusedTransformMixin, Plan):
     """Batched distributed sphere ↔ real-space transform."""
 
@@ -292,6 +390,9 @@ class PlaneWaveFFT(_FusedTransformMixin, Plan):
         self.plan = plan
         self._pack_idx = jnp.asarray(sphere.pack_indices())
         self._mask = jnp.asarray(sphere.mask())
+        self._lines = line_window_tables([sphere])
+        self._unpack_lines = _unpack_program(tin, self._lines,
+                                             sphere.extents)
 
     # ------------------------------------------------------------- execute
     # __call__/tune come from Plan; execution delegates to the inner plan
@@ -334,13 +435,13 @@ class PlaneWaveFFT(_FusedTransformMixin, Plan):
 
     # ------------------------------------------------- sphere pack/unpack
     def unpack(self, packed):
-        """(…, npacked) CSR coefficients → (…, d, d, d) bounding cube."""
+        """(…, npacked) CSR coefficients → (…, d, d, d) bounding cube, by
+        whole z-lines (:func:`unpack_lines`, one sphere)."""
         d = self.sphere.extents
+        lead = packed.shape[:-1]
         with jax.named_scope(UNPACK_SCOPE):
-            flat = jnp.zeros(packed.shape[:-1] + (math.prod(d),),
-                             packed.dtype)
-            flat = flat.at[..., self._pack_idx].set(packed)
-            return flat.reshape(packed.shape[:-1] + d)
+            c = packed.reshape(math.prod(lead), packed.shape[-1])
+            return self._unpack_lines(c).reshape(lead + d)
 
     def pack(self, cube):
         """(…, d, d, d) bounding cube → (…, npacked) CSR coefficients."""
@@ -374,6 +475,7 @@ class PlaneWaveFFT(_FusedTransformMixin, Plan):
         spheres expensive cache entries (DFT-matrix operands are shared
         across plans and accounted via ``shared_table_bytes``)."""
         return (int(self._pack_idx.nbytes) + int(self._mask.nbytes)
+                + sum(int(t.nbytes) for t in self._lines)
                 + self._fused_table_bytes() + super().private_bytes())
 
     def describe(self) -> str:
@@ -467,16 +569,15 @@ def padded_pack_tables(spheres) -> tuple[np.ndarray, np.ndarray]:
     """Index tables for a ragged batch of spheres sharing one bounding box.
 
     Every sphere's CSR pack order is padded to ``npacked_max = max_k
-    npacked_k``.  The per-k validity mask is baked into the table itself:
-    padded lanes carry the *dump-slot* index ``prod(extents)`` — one flat
-    cell past the bounding cube — so an unpack scatter routes whatever sits
-    in a padded lane into a slot that is dropped, and a pack gather reads
-    padded lanes from a slot that is always zero.  No runtime masking, no
-    extra transform math for the padding.
+    npacked_k``.  Padded lanes carry flat index 0, a cell inside the
+    bounding cube, so a gather through the table needs no bounds check;
+    ``valid`` then zeroes what those lanes read
+    (``StackedPlaneWaveFFT.pack``).  The unpack moves whole lines through
+    :func:`line_window_tables` and never reads a padded lane.
 
     Returns ``(idx, valid)``: ``idx`` is ``(nk, npacked_max)`` int32 flat
-    bounding-cube indices (dump slot for padded lanes), ``valid`` the
-    matching boolean lane mask.
+    bounding-cube indices (0 on padded lanes), ``valid`` the matching
+    boolean lane mask.
     """
     spheres = list(spheres)
     if not spheres:
@@ -488,8 +589,7 @@ def padded_pack_tables(spheres) -> tuple[np.ndarray, np.ndarray]:
                 f"ragged sphere batch must share one bounding box; got "
                 f"extents {s.extents} vs {ext}")
     npmax = max(s.npacked for s in spheres)
-    dump = math.prod(ext)
-    idx = np.full((len(spheres), npmax), dump, np.int32)
+    idx = np.zeros((len(spheres), npmax), np.int32)
     valid = np.zeros((len(spheres), npmax), bool)
     for k, s in enumerate(spheres):
         idx[k, :s.npacked] = s.pack_indices()
@@ -606,7 +706,7 @@ def padded_kinetic_table(spheres, box_length: float
     float32 holding ½|G+k|² per packed coefficient (units set by the cell
     side ``box_length`` — a reciprocal-lattice step is 2π/L), exactly
     **zero** on padded lanes; ``valid`` is the matching boolean lane mask
-    (the same one :func:`padded_pack_tables` bakes into its index table).
+    (the same one :func:`padded_pack_tables` returns).
 
     This is the dense-table counterpart of the ragged per-k kinetic
     ladders: because padded lanes carry exact zeros, the table can ride
@@ -631,11 +731,11 @@ class StackedPlaneWaveFFT(_FusedTransformMixin, Plan):
     spheres share the d³ bounding box, so their transforms differ only in
     the static pack tables — the staged-padding FFT itself can run once
     with batch ``nk·nbands`` instead of ``nk`` times with batch ``nbands``.
-    Packed coefficients are padded per k to ``(nk·nbands, npacked_max)``
-    with the validity masks baked into the pack/unpack tables (see
-    :func:`padded_pack_tables`): padded lanes are zeros on the transform
-    side and never read back, so raggedness costs only the padding
-    fraction, not correctness.
+    Packed coefficients are padded per k to ``(nk·nbands, npacked_max)``;
+    the unpack keeps only each line's z range and the pack zeroes the
+    padded lanes (see :func:`padded_pack_tables`), so padded lanes are
+    zeros on the transform side and never read back: raggedness costs
+    only the padding fraction, not correctness.
 
     The inner ``FftPlan`` is the same d³→n³ stacked plan the density build
     uses (pass it via ``plan=`` to share the cached object and its jitted
@@ -663,17 +763,12 @@ class StackedPlaneWaveFFT(_FusedTransformMixin, Plan):
                            backend=backend, policy=self.policy)
         self.plan = plan
         idx, valid = padded_pack_tables(self.spheres)
-        self._pad_idx = jnp.asarray(idx)
-        # validity is fully baked into the dump/zero slots of _pad_idx;
-        # the mask is kept host-side for introspection/tests only
-        self._valid = valid
+        self._valid = valid               # host copy, for valid_lanes()
         self.npacked_max = int(idx.shape[1])
-        # pack-side gather table: the dump slot is clipped back into the
-        # cube and masked with the lane validity instead, so ``pack`` never
-        # concatenates a zero slot onto the flattened cube per dispatch
-        cells = math.prod(self.extents)
-        self._pack_gather_idx = jnp.asarray(np.minimum(idx, cells - 1))
+        self._pack_gather_idx = jnp.asarray(idx)
         self._valid_dev = jnp.asarray(valid)
+        self._lines = line_window_tables(self.spheres)
+        self._unpack_lines = _unpack_program(tin, self._lines, self.extents)
 
     # ------------------------------------------------------------- queries
     @property
@@ -693,9 +788,8 @@ class StackedPlaneWaveFFT(_FusedTransformMixin, Plan):
     def valid_lanes(self) -> np.ndarray:
         """(nk, npacked_max) boolean lane-validity mask (host-side copy).
 
-        The same mask :func:`padded_pack_tables` bakes into the index
-        tables — True where a lane holds a real packed coefficient,
-        False on padding.
+        The mask :func:`padded_pack_tables` returns — True where a lane
+        holds a real packed coefficient, False on padding.
         """
         return self._valid.copy()
 
@@ -763,28 +857,20 @@ class StackedPlaneWaveFFT(_FusedTransformMixin, Plan):
     def unpack(self, padded):
         """``(nk·nbands, npacked_max)`` coefficients → ``(nk·nbands, d³)``.
 
-        Each k-block scatters through its own pack table; padded lanes land
-        in the dump slot and are dropped, so garbage there never reaches
-        the bounding cube.
+        Each k-block moves whole z-lines through its own line tables
+        (:func:`unpack_lines`): padded lanes fall outside every line's z
+        range, so garbage there never reaches the bounding cube.
         """
-        d = self.extents
-        cells = math.prod(d)
         with jax.named_scope(UNPACK_SCOPE):
-            c = padded.reshape(self.nk, self.nbands, self.npacked_max)
-            flat = jnp.zeros((self.nk, self.nbands, cells + 1), padded.dtype)
-            kk = jnp.arange(self.nk)[:, None, None]
-            bb = jnp.arange(self.nbands)[None, :, None]
-            flat = flat.at[kk, bb, self._pad_idx[:, None, :]].set(c)
-            return flat[..., :cells].reshape((self.nk * self.nbands,) + d)
+            return self._unpack_lines(padded)
 
     def pack(self, cube):
         """``(nk·nbands, d, d, d)`` cubes → ``(nk·nbands, npacked_max)``.
 
-        Padded lanes come out exactly zero, whatever the cube holds: the
-        gather table clips their dump slot back into the cube and the
-        precomputed validity mask zeroes the result (``jnp.where`` yields
-        +0.0, bit-identical to the old zero-slot gather) — no per-dispatch
-        zero-slot concatenate on the hot path.
+        Padded lanes come out exactly zero, whatever the cube holds: they
+        gather cell 0 and the precomputed validity mask zeroes the result
+        (``jnp.where`` yields +0.0) — no per-dispatch zero-slot
+        concatenate on the hot path.
         """
         d = self.extents
         cells = math.prod(d)
@@ -815,9 +901,10 @@ class StackedPlaneWaveFFT(_FusedTransformMixin, Plan):
     # ---------------------------------------------------------- accounting
     def private_bytes(self) -> int:
         """The ragged pack tables are per-sphere-set — never shared."""
-        return (int(self._pad_idx.nbytes) + int(self._valid.nbytes)
+        return (int(self._valid.nbytes)
                 + int(self._pack_gather_idx.nbytes)
                 + int(self._valid_dev.nbytes)
+                + sum(int(t.nbytes) for t in self._lines)
                 + self._fused_table_bytes() + super().private_bytes())
 
     def describe(self) -> str:

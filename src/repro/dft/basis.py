@@ -359,8 +359,9 @@ class PlaneWaveBasis:
 
         One ``StackedPlaneWaveFFT`` pair batching segment ``seg``'s
         nk_seg·nbands orbitals: each k-point's packed coefficients are
-        padded to the segment's own lane width (``pad_width``) with the
-        per-k validity baked into the pack/unpack index tables, so one
+        padded to the segment's own lane width (``pad_width``); the
+        unpack keeps each line's z range and the pack zeroes padded lanes
+        (``StackedPlaneWaveFFT``), so one
         Hamiltonian sweep is two batched distributed transforms per
         segment regardless of nk and nbands.  Served from the
         process-global PlanCache keyed by the segment's sphere set; the

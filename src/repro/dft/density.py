@@ -38,8 +38,8 @@ def density_from_stacked(basis, c_pad, occ, seg: int = 0) -> jnp.ndarray:
     are selected here, weights included, so summing the per-segment
     contributions (each carries the n³/ΔV prefactor, the sum is linear)
     gives exactly ρ.  With the default single segment this is the whole
-    density.  Padded lanes never reach the cube (the unpack scatter
-    routes them to the dump slot), so they contribute nothing to ρ.
+    density.  Padded lanes never reach the cube (the unpack keeps only
+    each line's z range), so they contribute nothing to ρ.
     Traceable — the jitted SCF step runs it under ``jax.jit``; ``occ``
     must be a trace-time constant (numpy).
     """

@@ -2,7 +2,7 @@
 
 The Hamiltonian hot chain ``pack(F(v_eff · F⁻¹(unpack(c))))`` pays two full
 ``(B, d, d, d)`` bounding-cube materializations per sweep: ``unpack``
-scatters packed CSR coefficients into a freshly-zeroed cube that the first
+writes packed CSR coefficients into a freshly-zeroed cube that the first
 line-DFT stage immediately re-reads, and ``pack`` gathers npacked lanes back
 out of a cube the last stage just wrote.  The CMU flexible-DFT framework
 (1904.10119) argues the data-layout permutation should be fused *into* the
@@ -122,16 +122,15 @@ def line_tables(spheres, nbands: int):
         if s.extents != (ex, ey, ez):
             raise ValueError(f"sphere batch must share one bounding box; "
                              f"got {s.extents} vs {(ex, ey, ez)}")
-        flat = s.pack_indices()
-        lines = flat // ez
-        # CSR order is line-major (columns ascend in (x, y)) with z
-        # contiguous ascending inside each line
-        uniq, first, counts = np.unique(lines, return_index=True,
-                                        return_counts=True)
-        start[k, uniq] = first
-        zlo[k, uniq] = flat[first] % ez
-        cnt[k, uniq] = counts
-        flag[uniq // ey] = 1
+        # one CSR column per non-empty (x, y) line, z contiguous inside it
+        # (the order ``pack_indices`` lays the lanes out in)
+        off, (xl, yl, zl) = s.offsets, s.lower
+        x = off["col_x"] - xl
+        lines = x * ey + (off["col_y"] - yl)
+        start[k, lines] = off["row_ptr"][:-1]
+        zlo[k, lines] = off["z_lo"] - zl
+        cnt[k, lines] = off["z_hi"] - off["z_lo"]
+        flag[x] = 1
     rep = functools.partial(np.repeat, repeats=nbands, axis=0)
     return rep(start), rep(zlo), rep(cnt), flag
 

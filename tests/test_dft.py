@@ -320,8 +320,8 @@ def test_stacked_plans_cached_and_shared_with_density(basis2):
 
 def test_stacked_padded_lanes_never_leak(basis2):
     """Garbage written into the padded lanes must not reach the packed
-    outputs: unpack routes padded lanes to the dump slot, pack reads them
-    from the zero slot."""
+    outputs: unpack keeps only each line's z range, pack zeroes padded
+    lanes."""
     inv, fwd = basis2.stacked_hamiltonian_plans()
     assert inv.padding_fraction > 0.0               # ragged ⇒ real padding
     rng = np.random.default_rng(12)
@@ -334,7 +334,7 @@ def test_stacked_padded_lanes_never_leak(basis2):
     lanes = np.repeat(valid, basis2.nbands, axis=0)  # (nk·nb, npmax)
     garbage = jnp.where(jnp.asarray(lanes), stacked,
                         jnp.asarray(1e6 + 1e6j, stacked.dtype))
-    # unpack: padded-lane garbage lands in the dump slot, not the cube
+    # unpack: padded-lane garbage never reaches the cube
     assert float(jnp.abs(inv.unpack(garbage)
                          - inv.unpack(stacked)).max()) == 0.0
     # pack after a round trip: padded lanes come out exactly zero

@@ -1,5 +1,7 @@
 """Plane-wave sphere transform: CSR offsets, pack/unpack, staged padding,
 ragged k-stacked batches."""
+import json
+
 import numpy as np
 import pytest
 
@@ -137,10 +139,9 @@ def test_padded_pack_tables_dump_slot_and_validity():
     idx, valid = padded_pack_tables(spheres)
     npmax = max(s.npacked for s in spheres)
     assert idx.shape == valid.shape == (2, npmax)
-    dump = 8 * 8 * 8
     for k, s in enumerate(spheres):
         np.testing.assert_array_equal(idx[k, :s.npacked], s.pack_indices())
-        assert (idx[k, s.npacked:] == dump).all()    # padded → dump slot
+        assert (idx[k, s.npacked:] == 0).all()       # padded → cell 0
         assert valid[k, :s.npacked].all()
         assert not valid[k, s.npacked:].any()
     with pytest.raises(ValueError, match="bounding box"):
@@ -174,6 +175,51 @@ def test_stacked_pair_matches_per_sphere_reference():
                                    rtol=1e-3, atol=2e-5)
 
 
+KSHIFTS = ((0.0, 0.0, 0.0), (0.5, 0.5, 0.5), (0.3, -0.2, 0.45))
+
+
+@pytest.mark.parametrize("nk", [1, 2, 3])
+@pytest.mark.parametrize("d", [8, 15, 16, 33])
+def test_line_window_unpack_matches_element_scatter(d, nk):
+    """The line-window unpack equals, bit for bit, each k's packed lanes
+    scattered through ``pack_indices()`` into a zero cube: NaN in the
+    padded lanes never reaches the cube, and -0.0 keeps its sign."""
+    from repro.core import kpoint_sphere
+    from repro.core.planewave import line_window_tables
+    spheres = [kpoint_sphere(d, k) for k in KSHIFTS[:nk]]
+    off, lo, hi = line_window_tables(spheres)
+    cnt = hi - lo
+    # full lines, empty lines and lines that start at z = 0 all occur
+    assert (cnt == d).any() and (cnt == 0).any()
+    assert ((lo == 0) & (cnt > 0)).any()
+    nb = 2
+    inv, _ = make_stacked_planewave_pair(ProcGrid.create([1]), 2 * d,
+                                         spheres, nb)
+    rng = np.random.default_rng(d * 10 + nk)
+    x = (rng.standard_normal((nk, nb, inv.npacked_max))
+         + 1j * rng.standard_normal((nk, nb, inv.npacked_max))
+         ).astype(np.complex64)
+    x[..., ::5] = complex(-0.0, -0.0)
+    want = np.zeros((nk, nb, d ** 3), np.complex64)
+    for k, s in enumerate(spheres):
+        x[k, :, s.npacked:] = complex(np.nan, np.nan)
+        want[k][:, s.pack_indices()] = x[k, :, :s.npacked]
+    want = want.reshape(nk * nb, d, d, d)
+    cube = np.asarray(inv.unpack(jnp.asarray(x.reshape(nk * nb, -1))))
+    assert not np.isnan(cube).any()
+    np.testing.assert_array_equal(cube, want)
+    np.testing.assert_array_equal(cube.view(np.uint32),
+                                  want.view(np.uint32))   # sign of zero
+    assert np.signbit(cube.real).sum() >= nk * nb
+    if nk == 1:
+        pinv, _ = make_planewave_pair(ProcGrid.create([1]), 2 * d,
+                                      spheres[0], nb)
+        one = np.asarray(pinv.unpack(
+            jnp.asarray(x[0, :, :spheres[0].npacked])))
+        np.testing.assert_array_equal(one.view(np.uint32),
+                                      want.view(np.uint32))
+
+
 def test_stacked_pair_shares_inner_plan_and_accounts_tables():
     """plan= wraps an existing d³→n³ FftPlan (no second build); the ragged
     tables are private bytes on top of the shared DFT-matrix tables."""
@@ -187,7 +233,8 @@ def test_stacked_pair_shares_inner_plan_and_accounts_tables():
     assert inv.plan is inv0.plan
     assert FftPlan.searches == searches          # wrapped, not re-planned
     assert fwd.plan is inv0.plan.inverse()
-    tables = int(inv._pad_idx.nbytes) + int(inv._valid.nbytes)
+    tables = (int(inv._pack_gather_idx.nbytes) + int(inv._valid.nbytes)
+              + sum(int(t.nbytes) for t in inv._lines))
     assert inv.private_bytes() >= tables
     assert inv.estimated_bytes() == inv.private_bytes() + sum(
         inv.shared_table_bytes().values())
@@ -266,6 +313,64 @@ assert np.abs(y-ref).max() / np.abs(ref).max() < 5e-6
 print("OK")
 """
     assert "OK" in dist(script)
+
+
+SHARDED_UNPACK = r"""
+import json, re
+import numpy as np, jax, jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.core import (ProcGrid, kpoint_sphere, make_planewave_pair,
+                        make_stacked_planewave_pair)
+from repro.core.planewave import UNPACK_SCOPE
+MOVES = re.compile(r"\b(all-gather|all-reduce|all-to-all|collective-permute)"
+                   r"(-start)?\(")
+spheres = [kpoint_sphere(8, k) for k in ((0, 0, 0), (0.5, 0.5, 0.5))]
+rng = np.random.default_rng(3)
+out = {}
+for shape in ([4], [2, 2]):
+    grid = ProcGrid.create(shape)
+    stacked, _ = make_stacked_planewave_pair(grid, 16, spheres, 4,
+                                             batch_axes=(0,))
+    single, _ = make_planewave_pair(grid, 16, spheres[0], 4, batch_axes=(0,))
+    sh = NamedSharding(grid.mesh, P(grid.axis_name(0), None))
+    for name, inv, rows in (("stacked", stacked, 8), ("single", single, 4)):
+        width = getattr(inv, "npacked_max", spheres[0].npacked)
+        spec = jax.ShapeDtypeStruct((rows, width), jnp.complex64, sharding=sh)
+        text = jax.jit(inv.unpack).lower(spec).compile().as_text()
+        moves = [ln for ln in text.splitlines()
+                 if MOVES.search(ln) and UNPACK_SCOPE in ln]
+        x = (rng.standard_normal((rows, width))
+             + 1j * rng.standard_normal((rows, width))).astype(np.complex64)
+        got = np.asarray(inv.unpack(jax.device_put(jnp.asarray(x), sh)))
+        want = np.zeros((rows, 8 ** 3), np.complex64)
+        for r in range(rows):
+            s = spheres[r // 4]
+            want[r, s.pack_indices()] = x[r, :s.npacked]
+        out[name + "-" + "x".join(map(str, shape))] = {
+            "moves": len(moves),
+            "equal": bool(np.array_equal(
+                got.reshape(rows, -1).view(np.uint32), want.view(np.uint32)))}
+print("UNPACK " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def sharded_unpack(dist):
+    text = dist(SHARDED_UNPACK, n_devices=4)
+    line = next(ln for ln in text.splitlines() if ln.startswith("UNPACK "))
+    return json.loads(line[len("UNPACK "):])
+
+
+@pytest.mark.parametrize("case", ["stacked-4", "single-4", "stacked-2x2",
+                                  "single-2x2"])
+def test_unpack_stays_on_its_shard_when_bands_are_sharded(sharded_unpack,
+                                                          case):
+    """On a grid that shards the bands (``batch_axes=(0,)``) each device
+    unpacks only its own bands: the compiled unpack moves no data between
+    devices, and its cubes equal the element scatter bit for bit."""
+    got = sharded_unpack[case]
+    assert got["moves"] == 0
+    assert got["equal"]
 
 
 def test_batch_plus_fft_grid_2d(dist):

@@ -96,6 +96,25 @@ def test_dft_pack_compiles_at_smoke_width(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
+# ------------------------------------------------- Fig. 9 sphere unpack
+def test_fig9_stacked_unpack_has_no_scatter(one_chip):
+    """The stacked unpack at the Fig. 9 width (d=128, 2 k-points × 16
+    bands) moves whole z-lines: no element scatter in the compiled
+    program, and its gathers stay under the unpack's scope."""
+    from repro.core import ProcGrid, make_stacked_planewave_pair
+    from repro.core.planewave import UNPACK_SCOPE
+
+    spheres = [kpoint_sphere(128, k) for k in KPTS]
+    inv, _ = make_stacked_planewave_pair(ProcGrid.create_abstract([1]), 256,
+                                         spheres, 16)
+    text = _compile(inv.unpack, _spec(one_chip, (32, inv.npacked_max),
+                                      jnp.complex64)).as_text()
+    assert "scatter(" not in text
+    gathers = [ln for ln in text.splitlines() if " gather(" in ln]
+    assert gathers
+    assert all(UNPACK_SCOPE in ln for ln in gathers)
+
+
 # -------------------------------------------------- Fig. 9 line stage
 @pytest.mark.parametrize("backend", ["matmul", "pallas"])
 def test_fig9_line_dft_stage_compiles(one_chip, backend):
