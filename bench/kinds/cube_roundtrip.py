@@ -49,6 +49,14 @@ def required_work(config: dict, params: dict) -> tuple[float, float]:
             float(2 * 2 * cells * 8))
 
 
+def scope_work(config: dict, params: dict) -> dict:
+    """(flops, bytes) that one cube's round trip requires of the line-DFT
+    layer, its only named layer: ``required_work``'s count, two
+    transforms of 5·N·log2 N flops, each reading and writing the n³
+    complex64 cube once."""
+    return {"fftb.line_dft": required_work(config, params)}
+
+
 class Cell:
     def __init__(self, ctx):
         from repro.core import Domain, fftb

@@ -60,6 +60,29 @@ def required_work(config: dict, params: dict) -> tuple[float, float]:
             float(sum(m * w[1] for m, w in parts)))
 
 
+def scope_work(config: dict, params: dict) -> dict:
+    """(flops, bytes) one iteration requires of each named transform
+    layer, counted from the configuration's shapes, whatever implements
+    the layer:
+
+    * ``fftb.line_dft``: every transform of the iteration,
+      ``required_work``: the transforms need no less than the packed
+      coefficients on one side of a sphere transform and the cube on
+      the other;
+    * ``fftb.unpack``: each orbital's packed coefficients read once and
+      written once, no flops, for each H apply (2 a band-update step)
+      and for the density;
+    * ``fftb.pack``: the same for each H apply.
+    """
+    packed = config["nbands"] * sum(
+        sphere.packed_points(config["diameter"], k).size
+        for k in config["kpts"])
+    applies = 2 * config["inner_steps"]
+    return {"fftb.line_dft": required_work(config, params),
+            "fftb.unpack": (0.0, float((applies + 1) * 2 * packed * 8)),
+            "fftb.pack": (0.0, float(applies * 2 * packed * 8))}
+
+
 def initial_orbitals(seed: int, config: dict, npm: int):
     """Orthonormal random orbitals per k-point, (nk, nb, npm) complex64,
     zero on each k-point's padded lanes — made on the device."""

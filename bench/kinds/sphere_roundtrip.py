@@ -54,6 +54,26 @@ def required_work(config: dict, params: dict) -> tuple[float, float]:
     return float(flops), float(nbytes)
 
 
+def scope_work(config: dict, params: dict) -> dict:
+    """(flops, bytes) that one orbital's round trip requires of each named
+    layer, counted from the configuration's shapes, whatever implements
+    the layer:
+
+    * ``fftb.line_dft``: the whole round trip's ``required_work``: the
+      transforms need no less than the packed coefficients on one side
+      and the cube on the other, and a route that pads the sphere into a
+      cube first moves more, never less;
+    * ``fftb.unpack`` and ``fftb.pack``: the orbital's packed
+      coefficients read once and written once (to or from their places
+      in the cube), and no flops: the layers only move data.
+    """
+    npk = np.mean([ref.packed_points(config["diameter"], k).size
+                   for k in config["kpts"]])
+    move = (0.0, float(2 * npk * 8))
+    return {"fftb.line_dft": required_work(config, params),
+            "fftb.unpack": move, "fftb.pack": move}
+
+
 class Cell:
     def __init__(self, ctx):
         from repro.core import kpoint_sphere, make_stacked_planewave_pair

@@ -1,0 +1,9 @@
+"""The ``fftb.unpack`` layer's share of its roofline, in %: the least time
+for one step's work under the scope (the kind's ``scope_work``) over
+the scope's device time per step.  Read for ``unpack_roofline.transform``
+and ``unpack_roofline.scf`` alike."""
+from bench import scopes
+
+
+def read(tr, info):
+    return scopes.roofline_share(tr, info, "fftb.unpack")

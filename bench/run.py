@@ -119,7 +119,7 @@ def chip_devices(registry: Registry, chips: int):
     return devs[:chips]
 
 
-def enable_cache() -> str:
+def enable_cache(trace: bool = False) -> str:
     import jax
     from repro.launch.compile_cache import enable_compile_cache
 
@@ -127,6 +127,13 @@ def enable_cache() -> str:
     # cache every program, however quick to compile, so that every run
     # after a checkout's first finds all of them (steady set-up)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if trace:
+        # the cache key leaves the instructions' metadata out unless told
+        # otherwise, and a hit then carries the scope names of whatever
+        # revision compiled first; a traced run reads the scope map from
+        # its programs, so it keys on them (untraced runs keep the key)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     return where
 
 
@@ -151,6 +158,7 @@ def run_cell(workload: str, *, seed: int, seconds: float, trace: bool,
     import jax
 
     from bench import common
+    from bench import scopes
     from bench import trace as btrace
 
     reg = registry or Registry()
@@ -204,6 +212,13 @@ def run_cell(workload: str, *, seed: int, seconds: float, trace: bool,
     log(f"hbm_gib={hbm / 2 ** 30:.4f} (compile-time memory_analysis) "
         f"memory_peak_bytes={device['memory_peak_bytes']} "
         f"(runtime peak_bytes_in_use)")
+    if trace:
+        # read before release, which may drop the compiled programs
+        t_map = time.perf_counter()
+        scope_maps = {name: scopes.op_scopes(p)
+                      for name, p in cell.programs.items()}
+        log(f"scope maps of {len(scope_maps)} programs took "
+            f"{time.perf_counter() - t_map:.3f} s")
     held = cell.release()
 
     values = {}
@@ -212,7 +227,10 @@ def run_cell(workload: str, *, seed: int, seconds: float, trace: bool,
         shutil.rmtree(tdir, ignore_errors=True)
         info = {"programs": kind.PROGRAMS, "units_per_step":
                 cell.units_per_step, "peaks": peaks, "chips": len(devices),
-                "work": kind.required_work(config, params), "log": log}
+                "work": kind.required_work(config, params), "log": log,
+                "scopes": scope_maps}
+        if hasattr(kind, "scope_work"):
+            info["scope_work"] = kind.scope_work(config, params)
         for m in reg.per_layer(workload):
             v = reg.metric_reader(m["name"]).read(tr, info)
             if v is not None:
@@ -264,7 +282,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     reg = Registry()
     devices = chip_devices(reg, int(reg.workload(args.workload)["chips"]))
-    log(f"compile cache {enable_cache()}")
+    log(f"compile cache {enable_cache(trace=bool(args.trace))}")
     emit(run_cell(args.workload, seed=args.seed, seconds=args.seconds,
                   trace=bool(args.trace), registry=reg, devices=devices))
     return 0

@@ -19,6 +19,13 @@ each operation by its instruction (``%fusion.12 = …``, ``trace.short_op``).
   ``bench_inverse`` and ``bench_forward``), so the maps are per program,
   and an operation takes the program whose run on the device encloses
   it, as ``trace.top_ops`` does.
+* Where one jitted callee is called from several places (the unpack's
+  loop, from each H apply and from the density), the compiler may keep
+  one body for all of them and prefix each call site's path to its
+  instructions' ``op_name``: the path then names the program's root more
+  than once, and every call site's layers.  Such an operation takes the
+  path of the innermost operation that encloses it in time on the device
+  (the ``while`` of the call site that ran it).
 * ``scope_ns`` is the union of the intervals of the operations whose
   path has the scope as one component, inside the window, mean over the
   devices: a ``while`` and the operations of its body, or an async copy
@@ -154,23 +161,40 @@ def instruction(op_name: str) -> str:
     return op_name.split(" = ", 1)[0].strip()
 
 
+def merged(path: str) -> bool:
+    """Whether ``path`` joins several call sites' paths: its first
+    component, the program's root, comes again later."""
+    parts = components(path)
+    return bool(parts) and parts[0] in parts[1:]
+
+
 def attributed_ops(dev, scopes: dict, programs):
     """``(path or None, start, end)`` of each operation in the device's
     window, its path looked up in the map of the program whose run
     encloses it (None where that program has no map or the instruction
-    is missing).  Nothing where the device ran none of ``programs``."""
+    is missing); a merged path gives way to that of the innermost
+    enclosing operation whose path is not merged.  Nothing where the
+    device ran none of ``programs``."""
     w = trace.window(dev, programs)
     if w is None:
         return
     mods = sorted((s, s + d, trace.program_of(n))
                   for n, s, d, _ in dev.modules)
     starts = [m[0] for m in mods]
-    for name, s, d in dev.ops:
+    open_ops: list = []            # (end, path) of the enclosing operations
+    for name, s, d in sorted(dev.ops, key=lambda op: (op[1], -op[2])):
         if s < w[0] or s > w[1]:
             continue
         i = bisect.bisect_right(starts, s) - 1
         prog = mods[i][2] if i >= 0 and s <= mods[i][1] else None
-        yield scopes.get(prog, {}).get(instruction(name)), s, s + d
+        path = scopes.get(prog, {}).get(instruction(name))
+        while open_ops and open_ops[-1][0] < s + d:
+            open_ops.pop()
+        if path is not None and merged(path):
+            path = next((p for _, p in reversed(open_ops)
+                         if p is not None and not merged(p)), path)
+        open_ops.append((s + d, path))
+        yield path, s, s + d
 
 
 def _per_device(tr, scopes, programs, keep):
@@ -241,3 +265,27 @@ def unscoped_share(tr, info) -> float | None:
     if ns is None or occ is None or not occ[0]:
         return None
     return 100.0 * ns / (occ[0] * 1e9)
+
+
+def roofline_share(tr, info, scope: str) -> float | None:
+    """The layer's share of its roofline, in %: the least time the chips
+    could take for one step's work under ``scope`` — the larger of flops
+    over the bf16 peak and bytes over the HBM bandwidth, both from
+    ``bench/peaks.json`` times the chips, the work from the kind's
+    ``scope_work`` (per unit, times ``units_per_step``) — over the
+    scope's device time per step (``per_step_ms``).  None where the
+    peaks, the layer's work or its device time are missing."""
+    work = info.get("scope_work", {}).get(scope)
+    peaks = info.get("peaks")
+    ms = per_step_ms(tr, info, scope)
+    if work is None or peaks is None or ms is None:
+        return None
+    flops, nbytes = work
+    units, chips = info["units_per_step"], info["chips"]
+    t_flops = units * flops / (chips * peaks["bf16_flops_per_s"])
+    t_bytes = units * nbytes / (chips * peaks["hbm_bytes_per_s"])
+    bound = "memory" if t_bytes >= t_flops else "compute"
+    info["log"](f"{scope} roofline: {bound}-bound, least "
+                f"{max(t_flops, t_bytes) * 1e3:.4f} ms/step against "
+                f"{ms:.4f} ms on the device")
+    return 100.0 * max(t_flops, t_bytes) / (ms / 1e3)

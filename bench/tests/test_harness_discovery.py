@@ -39,6 +39,20 @@ def test_every_per_layer_metric_has_a_reader():
         assert callable(REG.metric_reader(m["name"]).read), m["name"]
 
 
+def test_every_layer_roofline_has_its_layers_work():
+    """A ``<layer>_roofline`` metric reads the kind's ``scope_work``: every
+    cell it lists runs a kind that counts its layers' work."""
+    for m in REG.benchmark["per_layer"]:
+        if "_roofline" not in m["name"]:
+            continue
+        for cell in m["workloads"]:
+            mix = REG.traffic(REG.workload(cell)["traffic"])
+            kind = REG.kind(mix["kind"])
+            work = kind.scope_work(REG.config(REG.workload(cell)["config"]),
+                                   mix)
+            assert work and all(f >= 0 and b > 0 for f, b in work.values())
+
+
 def test_benchmark_file_keeps_the_contract_shapes():
     bench = REG.benchmark
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
@@ -130,3 +144,163 @@ def test_split_metric_shares_its_reader(name):
     """``<metric>.<split>`` falls back to ``metrics/<metric>.py``."""
     assert REG.metric_reader(name).__file__ == \
         REG.metric_reader("idle_share").__file__
+
+
+PROBE_KIND = '''"""A kind whose one program names a layer no other kind has."""
+import time
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+
+from bench import common
+
+PROGRAMS = ("bench_probe",)
+CHECKS = ("probe_err",)
+
+
+def window_metrics(units, steps, seconds):
+    return {"roundtrip_rate": units / seconds}
+
+
+def required_work(config, params):
+    return 1.0, 1.0
+
+
+def scope_work(config, params):
+    return {"scf.probe": (0.0, 8.0 * config["n"] ** 2)}
+
+
+class Cell:
+    def __init__(self, ctx):
+        n = ctx.config["n"]
+
+        def bench_probe(x):
+            with jax.named_scope("scf.probe"):
+                return jnp.sin(x) * 2.0
+
+        self.prog = common.compile_program(
+            "bench_probe", bench_probe,
+            jax.ShapeDtypeStruct((n, n), jnp.float32))
+        self.programs = {"bench_probe": self.prog}
+        self.units_per_step = 1
+        self.x = jnp.ones((n, n), jnp.float32)
+        self.y = None
+
+    def step(self, i):
+        self.y = self.prog(self.x)
+        self.y.block_until_ready()
+
+    def warm(self):
+        t = time.perf_counter()
+        self.step(-1)
+        return time.perf_counter() - t
+
+    def release(self):
+        # as the SCF kind does: the compiled programs go with the state
+        held = {"y": np.asarray(self.y), "x": np.asarray(self.x)}
+        self.programs = {}
+        return held
+
+    def readings(self, held):
+        want = 2.0 * np.sin(held["x"])
+        return {"probe_err": float(np.abs(held["y"] - want).max())}
+
+
+def build(ctx):
+    return Cell(ctx)
+'''
+
+
+class SpyRegistry(Registry):
+    """Keeps what the harness hands each per-layer reader."""
+
+    def __init__(self, root):
+        super().__init__(root)
+        self.handed = {}
+
+    def metric_reader(self, name):
+        reader = super().metric_reader(name)
+        handed = self.handed
+
+        class Spy:
+            @staticmethod
+            def read(tr, info):
+                handed[name] = info
+                return reader.read(tr, info)
+        return Spy
+
+
+def test_a_new_layer_is_new_files_only(tmp_path):
+    """A kind with a layer of its own, its time reader and its roofline
+    share: files and entries only.  A traced run hands the readers the
+    scope map of every program the kind runs and the kind's work per
+    layer; the readers then read the layer from a trace."""
+    from bench import scopes
+    from bench.tests.test_harness_scopes import (SCF, _fixture, _has,
+                                                 _plain_scope_ns, _steps)
+
+    reg = tiny_registry(tmp_path)
+    bench_dir = reg.bench_dir
+    files = {"kinds/probe_layer.py": PROBE_KIND,
+             "traffic/probe.json": json.dumps({"kind": "probe_layer"}),
+             "limits/tiny-probe.json": json.dumps(
+                 {"limits": {"probe_err": 1e-5}}),
+             "metrics/probe_ms.py":
+                 "from bench import scopes\n\n\ndef read(tr, info):\n"
+                 "    return scopes.per_step_ms(tr, info, 'scf.probe')\n",
+             "metrics/probe_roofline.py":
+                 "from bench import scopes\n\n\ndef read(tr, info):\n"
+                 "    return scopes.roofline_share(tr, info, 'scf.probe')\n"}
+    for rel, text in files.items():
+        with open(os.path.join(bench_dir, rel), "w") as fh:
+            fh.write(text)
+    path = os.path.join(reg.root, "BENCHMARK.json")
+    bench = json.load(open(path))
+    bench["workloads"].append({"name": "tiny-probe",
+                               "config": "fig9-planewave",
+                               "traffic": "probe", "chips": 1,
+                               "why": "one program, one new layer"})
+    for m in bench["end_to_end"]:
+        if m["name"] == "roundtrip_rate":
+            m["workloads"].append("tiny-probe")
+    for name, unit, better in (("probe_ms", "ms/step", "lower"),
+                               ("probe_roofline", "%", "higher")):
+        bench["per_layer"].append({
+            "name": name, "unit": unit, "better": better,
+            "source": "device_trace", "layer": "probe",
+            "moves": "roundtrip_rate", "workloads": ["tiny-probe"]})
+    json.dump(bench, open(path, "w"))
+
+    reg = SpyRegistry(reg.root)
+    res = run_cell("tiny-probe", seed=5, seconds=0.2, trace=True,
+                   registry=reg, devices=jax.devices()[:1])
+    assert res["correct"]
+    assert set(reg.handed) == {"probe_ms", "probe_roofline"}
+    info = reg.handed["probe_roofline"]
+    kind = reg.kind("probe_layer")
+    assert set(info["scopes"]) == set(kind.PROGRAMS)
+    assert any("scf.probe" in scopes.components(p)
+               for p in info["scopes"]["bench_probe"].values())
+    assert info["scope_work"] == {"scf.probe": (0.0, 8.0 * 16 ** 2)}
+    # a CPU trace has no device plane: both are left out, not read as 0
+    assert res["metrics"] == {}
+
+    # on a trace from the chip the new readers read the layer: the
+    # recorded SCF step's Hartree ops stand in for it, renamed
+    tr, table = _fixture("trace_scoped_scf_1chip.json")
+    renamed = {prog: {ins: path.replace("scf.hartree", "scf.probe")
+                      for ins, path in m.items()}
+               for prog, m in table.items()}
+    info = dict(info, programs=SCF, scopes=renamed, units_per_step=1,
+                peaks=REG.peaks("TPU v5 lite"), log=lambda msg: None)
+    got_ms = reg.metric_reader("probe_ms").read(tr, info)
+    want_ms = (_plain_scope_ns(tr, table, SCF, _has("scf.hartree"))
+               / _steps(tr, SCF[0]) / 1e6)
+    assert got_ms is not None and got_ms > 0
+    assert got_ms == pytest.approx(want_ms, rel=1e-9)
+    share = reg.metric_reader("probe_roofline").read(tr, info)
+    least_ms = 8.0 * 16 ** 2 / info["peaks"]["hbm_bytes_per_s"] * 1e3
+    assert share == pytest.approx(100.0 * least_ms / want_ms, rel=1e-9)
+    assert 0 < share < 100

@@ -1,4 +1,5 @@
-"""Device time by the program's layer names (``bench/scopes.py``).
+"""Device time by the program's layer names (``bench/scopes.py``), and
+each layer's share of its roofline.
 
 The attribution is checked on hand-made HLO text and traces, where the
 answer is known by construction, and on two small traces recorded on a
@@ -20,6 +21,7 @@ import pytest
 from bench import scopes
 from bench import trace as bt
 from bench.registry import Registry
+from bench.tests.harness_testkit import run_child
 
 DATA = os.path.join(os.path.dirname(__file__), "data")
 SPHERE = ("bench_inverse", "bench_forward")
@@ -199,6 +201,33 @@ def test_nested_and_overlapping_operations_count_once():
     assert scopes.scope_ns(tr, TOY, SPHERE, "no.such") is None
 
 
+def test_a_shared_loop_body_takes_the_loop_that_ran_it():
+    """One loop body serves two call sites, and its instruction's
+    ``op_name`` joins both sites' paths: each run counts under the scope
+    of the ``while`` that encloses it on the device, and once."""
+    both = ("jit/scf.density/fftb.unpack/jit(run)/jit/scf.hamiltonian/"
+            "fftb.unpack/jit(run)/while/body/gather")
+    table = {"bench_scf_step": {
+        "%while.1": "jit/scf.hamiltonian/fftb.unpack/jit(run)/while",
+        "%while.2": "jit/scf.density/fftb.unpack/jit(run)/while",
+        "%fusion.9": both}}
+    mods = [["jit_bench_scf_step(1)", 0.0, 100.0, 1]]
+    ops = [["%while.1 = (…) while(…)", 0.0, 40.0],
+           ["%fusion.9 = c64[…] fusion(…)", 5.0, 30.0],
+           ["%while.2 = (…) while(…)", 50.0, 20.0],
+           ["%fusion.9 = c64[…] fusion(…)", 55.0, 10.0]]
+    tr = bt.Trace([bt.Device("/device:TPU:0", mods, ops)], [])
+    assert scopes.merged(both)
+    assert not scopes.merged(table["bench_scf_step"]["%while.1"])
+    paths = [p for p, _, _ in scopes.attributed_ops(tr.devices[0], table,
+                                                    SCF)]
+    assert paths.count(table["bench_scf_step"]["%while.1"]) == 2
+    assert paths.count(table["bench_scf_step"]["%while.2"]) == 2
+    assert scopes.scope_ns(tr, table, SCF, "scf.hamiltonian") == 40.0
+    assert scopes.scope_ns(tr, table, SCF, "scf.density") == 20.0
+    assert scopes.scope_ns(tr, table, SCF, "fftb.unpack") == 60.0
+
+
 def test_a_program_without_layer_names_reads_none():
     tr = _toy_trace()
     bare = {p: {k: "" for k in m} for p, m in TOY.items()}
@@ -206,9 +235,56 @@ def test_a_program_without_layer_names_reads_none():
     assert scopes.unscoped_ns(tr, bare, SPHERE) is None
     assert scopes.unscoped_share(tr, info) is None
     assert scopes.per_step_ms(tr, info, "fftb.unpack") is None
-    # and the harness as it stands gives no map at all
+    # nor does a run that hands its readers no map
     assert scopes.per_step_ms(tr, {"programs": SPHERE}, "fftb.unpack") \
         is None
+
+
+PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
+
+
+def _share_info(**change):
+    info = {"programs": SPHERE, "scopes": TOY, "peaks": PEAKS,
+            "units_per_step": 2, "chips": 1, "log": lambda msg: None,
+            "scope_work": {"fftb.unpack": (0.0, 10.0),
+                           "fftb.line_dft": (15e3, 1.0),
+                           "no.such": (1.0, 1.0)}}
+    info.update(change)
+    return info
+
+
+def test_roofline_share_takes_the_larger_bound_over_the_scope_time():
+    tr = _toy_trace()
+    # bytes bound: 2 units × 10 B at 1 GB/s = 20 ns against the unpack's
+    # 40 ns on the device
+    assert scopes.roofline_share(tr, _share_info(), "fftb.unpack") == \
+        pytest.approx(50.0)
+    # flops bound: 2 × 15e3 at 1e12 = 30 ns against the line DFT's 30 ns
+    logged = []
+    info = _share_info(log=logged.append)
+    assert scopes.roofline_share(tr, info, "fftb.line_dft") == \
+        pytest.approx(100.0)
+    assert "compute-bound" in logged[-1]
+    # the chips share the work: half the least time
+    assert scopes.roofline_share(tr, _share_info(chips=2),
+                                 "fftb.unpack") == pytest.approx(25.0)
+
+
+def test_roofline_share_reads_none_where_a_number_is_missing():
+    tr = _toy_trace()
+    # no peaks (not a chip), no work for the layer, no map, no device
+    # time under the scope: nothing to read, never 0
+    assert scopes.roofline_share(tr, _share_info(peaks=None),
+                                 "fftb.unpack") is None
+    assert scopes.roofline_share(tr, _share_info(), "fftb.pack") is None
+    assert scopes.roofline_share(tr, _share_info(scope_work={}),
+                                 "fftb.unpack") is None
+    info = _share_info()
+    del info["scope_work"]
+    assert scopes.roofline_share(tr, info, "fftb.unpack") is None
+    assert scopes.roofline_share(tr, _share_info(scopes={}),
+                                 "fftb.unpack") is None
+    assert scopes.roofline_share(tr, _share_info(), "no.such") is None
 
 
 # ------------------------------------------------- recorded on the chip
@@ -249,6 +325,45 @@ def test_reader_gives_scope_time_per_step(request, name, cell, scope):
     # without the map (the harness as it stands) the reader is silent
     assert Registry().metric_reader(name).read(
         tr, {"programs": programs}) is None
+
+
+#: the recorded cells' shapes (module docstring)
+RECORDED = {"sphere": ("sphere_roundtrip", 8,
+                       {"n": 64, "diameter": 32, "nbands": {"1": 4},
+                        "kpts": [[0.0, 0.0, 0.0], [0.5, 0.5, 0.5]]}),
+            "scf": ("scf_iterations", 1,
+                    {"n": 32, "diameter": 16, "nbands": 2,
+                     "inner_steps": 2,
+                     "kpts": [[0.0, 0.0, 0.0], [0.5, 0.5, 0.5]]})}
+V5E = Registry().peaks("TPU v5 lite")
+SHARES = [("unpack_roofline.transform", "sphere", "fftb.unpack"),
+          ("pack_roofline.transform", "sphere", "fftb.pack"),
+          ("line_dft_roofline.transform", "sphere", "fftb.line_dft"),
+          ("unpack_roofline.scf", "scf", "fftb.unpack"),
+          ("pack_roofline.scf", "scf", "fftb.pack"),
+          ("line_dft_roofline.scf", "scf", "fftb.line_dft")]
+
+
+@pytest.mark.parametrize("name,cell,scope", SHARES)
+def test_share_reader_gives_least_time_over_scope_time(request, name, cell,
+                                                       scope):
+    tr, table = request.getfixturevalue(cell)
+    kind, units, cfg = RECORDED[cell]
+    programs = SPHERE if cell == "sphere" else SCF
+    work = Registry().kind(kind).scope_work(cfg, {})
+    info = {"programs": programs, "scopes": table, "scope_work": work,
+            "peaks": V5E, "units_per_step": units, "chips": 1,
+            "log": lambda msg: None}
+    got = Registry().metric_reader(name).read(tr, info)
+    flops, nbytes = work[scope]
+    least_s = units * max(flops / V5E["bf16_flops_per_s"],
+                          nbytes / V5E["hbm_bytes_per_s"])
+    step_s = (_plain_scope_ns(tr, table, programs, _has(scope))
+              / _steps(tr, programs[0]) / 1e9)
+    assert got == pytest.approx(100.0 * least_s / step_s, rel=1e-9)
+    assert 0 < got < 100
+    assert Registry().metric_reader(name).read(
+        tr, dict(info, scope_work={})) is None
 
 
 @pytest.mark.parametrize("name,cell", [("unscoped_share.transform", "sphere"),
@@ -295,3 +410,55 @@ def test_recorded_programs_share_instruction_names(sphere):
     shared = set(table["bench_inverse"]) & set(table["bench_forward"])
     assert any(table["bench_inverse"][n] != table["bench_forward"][n]
                for n in shared)
+
+
+# ------------------------------------------------- the compile cache's key
+CACHE_CHILD = """
+import json, os
+os.environ["JAX_COMPILATION_CACHE_DIR"] = {cache!r}
+import jax, jax.numpy as jnp
+from bench import common, scopes
+from bench.run import CompileLog, enable_cache
+
+enable_cache(trace={trace!r})
+log = CompileLog()
+seen = []
+for scope in {names!r}:
+    def probe(x):
+        with jax.named_scope(scope):
+            return jnp.sin(x) * 2.0
+    prog = common.compile_program(
+        "bench_probe", probe, jax.ShapeDtypeStruct((8,), jnp.float32))
+    paths = scopes.op_scopes(prog).values()
+    seen.append(sorted({{c for p in paths for c in scopes.components(p)
+                         if scopes.is_layer(c)}}))
+print(json.dumps({{"seen": seen, "hits": log.snapshot()[2]}}))
+"""
+
+
+def _compile_probes(cache, trace, names):
+    """Compile one program per scope name in a child process, each the
+    same program but for the name, through ``run.py``'s cache set-up;
+    returns the layer names each compiled program carries and the
+    persistent-cache hits."""
+    out = run_child(CACHE_CHILD.format(cache=str(cache), trace=trace,
+                                       names=names), 1, timeout=300)
+    return json.loads(out.strip().splitlines()[-1])
+
+
+def test_a_traced_run_maps_the_names_of_the_code_it_compiled(tmp_path):
+    """Two revisions' programs that differ only in a scope name share a
+    cache: the traced set-up keys on the names, so the second maps its
+    own, and a later traced run of either still finds its programs."""
+    first = _compile_probes(tmp_path, True, ["fftb.alpha", "fftb.beta"])
+    assert first == {"seen": [["fftb.alpha"], ["fftb.beta"]], "hits": 0}
+    again = _compile_probes(tmp_path, True, ["fftb.beta", "fftb.alpha"])
+    assert again == {"seen": [["fftb.beta"], ["fftb.alpha"]], "hits": 2}
+
+
+def test_an_untraced_run_keeps_the_cache_key(tmp_path):
+    """The untraced set-up keys as before: the second program is a hit,
+    and carries the names of the program that filled the entry — why a
+    traced run may not read its map so."""
+    got = _compile_probes(tmp_path, False, ["fftb.alpha", "fftb.beta"])
+    assert got == {"seen": [["fftb.alpha"], ["fftb.alpha"]], "hits": 1}

@@ -90,3 +90,48 @@ def test_work_is_per_orbital_not_per_batch():
     assert kind.required_work(dict(FIG9, nbands={"1": 16}), mix) == \
         kind.required_work(dict(FIG9, nbands={"1": 32}), mix)
     assert math.isfinite(kind.required_work(FIG9, {})[0])
+
+
+# ------------------------------------------------ work of each named layer
+SMALL = {"n": 16, "diameter": 8, "kpts": [[0.0, 0.0, 0.0], [0.5, 0.5, 0.5]]}
+#: the two spheres' packed points at d = 8, counted by the program's own
+#: ``kpoint_sphere`` (``test_reference_geometry_matches_the_program``)
+NPK8 = (280, 254)
+FFT16 = 5 * 4096 * 12                  # 5·N·log2 N at N = 16³
+
+
+def test_small_spheres_hold_the_counted_points():
+    assert tuple(ref_sphere.packed_points(8, k).size
+                 for k in SMALL["kpts"]) == NPK8
+
+
+def test_sphere_layer_work():
+    kind = REG.kind("sphere_roundtrip")
+    work = kind.scope_work(SMALL, REG.traffic("sphere-2x16"))
+    assert set(work) == {"fftb.line_dft", "fftb.unpack", "fftb.pack"}
+    # an orbital (267 points on average): two transforms, each the
+    # packed points on one side and the 4096-point cube on the other
+    assert work["fftb.line_dft"] == (2 * FFT16, 2 * (267 + 4096) * 8)
+    assert work["fftb.unpack"] == (0.0, 2 * 267 * 8)
+    assert work["fftb.pack"] == (0.0, 2 * 267 * 8)
+
+
+def test_cube_layer_work():
+    work = REG.kind("cube_roundtrip").scope_work(SMALL, {"batch": 2})
+    # one cube: two transforms, each reading and writing 4096 points
+    assert work == {"fftb.line_dft": (2 * FFT16, 2 * 2 * 4096 * 8)}
+
+
+def test_scf_layer_work():
+    kind = REG.kind("scf_iterations")
+    cfg = dict(SMALL, nbands=2, inner_steps=2)
+    work = kind.scope_work(cfg, {})
+    packed = 2 * sum(NPK8)             # 2 bands of each k-point: 1068
+    # 2 steps × 2 H applies of 4 orbitals: 16 round trips (32 sphere
+    # transforms, 16 unpacks, 16 packs); the density: 4 unpacks and 4
+    # inverses; two Hartree solves: 4 cube transforms
+    assert work["fftb.line_dft"] == (
+        (32 + 4 + 4) * FFT16,
+        (9 * (packed + 4 * 4096) + 4 * 2 * 4096) * 8)
+    assert work["fftb.unpack"] == (0.0, 5 * 2 * packed * 8)
+    assert work["fftb.pack"] == (0.0, 4 * 2 * packed * 8)
